@@ -1,44 +1,29 @@
-"""Version-portable JAX substrate (DESIGN.md §7).
+"""The JAX substrate every other module imports (DESIGN.md §7).
 
-The distributed MapReduce-SVM path targets the shard_map surface as it
-exists across JAX 0.4.3x → 0.8.x. The relevant names drifted between
-those versions, so this module is the ONE place allowed to touch them;
-every other file imports the stable spellings below.
+The repo targets the installed JAX, 0.9.0. The sharding and collective
+names it uses live here, under one spelling each, so a later JAX that
+moves them is absorbed in this file alone:
 
-Drift handled here:
-
-* ``jax.shard_map`` (new) vs ``jax.experimental.shard_map.shard_map``
-  (0.4.x), and the ``check_vma`` (new) vs ``check_rep`` (old) kwarg.
-* ``jax.lax.pcast`` (transitional) / ``jax.lax.pvary`` (new) /
-  neither (0.4.x, where shard_map has no vma types at all and the
-  correct behaviour is the identity).
-* ``AbstractMesh((16, 16), ("data", "model"))`` (new positional
-  ``axis_sizes, axis_names``) vs the 0.4.x
-  ``AbstractMesh(shape_tuple=(("data", 16), ("model", 16)))``.
-* ``jax.make_mesh`` (0.4.35+) vs hand-rolled ``Mesh`` over reshaped
-  ``jax.devices()``.
-* ``jax.tree.map`` (0.4.25+) vs ``jax.tree_util.tree_map``.
-* ``jax.lax.axis_index`` over a TUPLE of axis names (flattened index),
-  which older versions only accept for a single name.
-* ``jax.distributed.initialize`` kwarg drift (newer versions grow
-  kwargs like ``coordinator_bind_address``/``cluster_detection_method``
-  that 0.4.x lacks), the ``jax_cpu_collectives_implementation`` config
-  (spelled ``jax_cpu_enable_gloo_collectives`` on some versions, absent
-  on others), and ``jax.make_array_from_process_local_data`` (newer)
-  vs hand-assembly over ``make_array_from_single_device_arrays``.
+* ``shard_map`` with the ``check_vma`` replication checker;
+* ``pvary`` (``jax.lax.pcast(..., to="varying")``) for loop carries
+  built from constants inside ``shard_map``;
+* meshes: ``make_mesh`` gives every axis the ``Auto`` type, so bare
+  ``PartitionSpec`` constraints and ``shard_map`` keep the meaning the
+  model code was written for (``jax.make_mesh`` defaults to
+  ``Explicit`` axes);
+* collectives over a tuple of mesh axes (flattened row-major index);
+* the multi-process runtime (``jax.distributed.initialize``, gloo CPU
+  collectives, ``jax.make_array_from_process_local_data``).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 
 def jax_version() -> Tuple[int, ...]:
-    """Installed JAX version as a comparable int tuple, e.g. (0, 4, 37)."""
+    """Installed JAX version as a comparable int tuple, e.g. (0, 9, 0)."""
     parts = []
     for p in jax.__version__.split(".")[:3]:
         digits = "".join(c for c in p if c.isdigit())
@@ -46,98 +31,33 @@ def jax_version() -> Tuple[int, ...]:
     return tuple(parts)
 
 
-# ---------------------------------------------------------------------------
-# Pytree mapping.
-# ---------------------------------------------------------------------------
-
-try:
-    tree_map = jax.tree.map
-except AttributeError:                                    # pragma: no cover
-    tree_map = jax.tree_util.tree_map
+tree_map = jax.tree.map
 
 
 # ---------------------------------------------------------------------------
-# shard_map.
+# shard_map and varying-manual-axes (vma) marking.
 # ---------------------------------------------------------------------------
-
-def _resolve_shard_map() -> Callable:
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        return impl
-    from jax.experimental.shard_map import shard_map as impl
-    return impl
-
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None, **kwargs) -> Callable:
-    """``jax.shard_map`` with one calling convention on every JAX.
-
-    ``check_vma`` maps onto whichever replication/varying-manual-axes
-    checker kwarg the installed version accepts — the name is chosen by
-    signature, not by where the impl lives, because ~0.6.x exposes a
-    top-level ``jax.shard_map`` that still spells it ``check_rep``.
-    ``None`` leaves the version default in place.
-    """
-    impl = _resolve_shard_map()
-    kw = dict(kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default."""
     if check_vma is not None:
-        try:
-            params = inspect.signature(impl).parameters
-        except (TypeError, ValueError):
-            params = None                    # unsignature-able: probe below
-        if params is None or "check_vma" in params:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kw["check_rep"] = check_vma
-        # else: checker kwarg gone entirely → run the version default
-    try:
-        return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    except TypeError:
-        if "check_vma" in kw:                # probe failed: try old spelling
-            kw["check_rep"] = kw.pop("check_vma")
-            try:
-                return impl(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, **kw)
-            except TypeError:
-                pass
-        kw.pop("check_rep", None)
-        return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
-
-# ---------------------------------------------------------------------------
-# Varying-manual-axes (vma) marking.
-# ---------------------------------------------------------------------------
 
 def pvary(tree: Any, axes: Sequence[str]) -> Any:
     """Mark a pytree as device-varying over shard_map manual ``axes``.
 
-    Needed on vma-typed JAX (0.7+) because while_loop carries built from
-    constants type as axis-invariant while loop-body outputs are
-    varying. Resolution chain: ``jax.lax.pcast(..., to="varying")`` →
-    ``jax.lax.pvary`` → identity. On JAX without either primitive the
-    identity IS the correct lowering (no vma types exist to satisfy),
-    so the chain never raises — only degrades.
+    while_loop carries built from constants type as axis-invariant,
+    while loop-body outputs are varying; this casts the carry up front.
+    Outside ``shard_map`` it is the identity.
     """
     axes = tuple(axes)
     if not axes:
         return tree
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        try:
-            return tree_map(lambda x: pcast(x, axes, to="varying"), tree)
-        except Exception:               # kwarg drift / unbound axis name
-            pass
-    pvary_prim = getattr(jax.lax, "pvary", None)
-    if pvary_prim is not None:
-        try:
-            return tree_map(lambda x: pvary_prim(x, axes), tree)
-        except Exception:
-            # Unbound axis name, i.e. called outside shard_map on
-            # vma-typed JAX: identity is the correct no-op there too.
-            # pvary only annotates types — degrading never changes
-            # values, so swallowing here cannot mask a numeric bug.
-            pass
-    return tree
+    return tree_map(lambda x: jax.lax.pcast(x, axes, to="varying"), tree)
 
 
 # ---------------------------------------------------------------------------
@@ -146,38 +66,21 @@ def pvary(tree: Any, axes: Sequence[str]) -> Any:
 
 def make_abstract_mesh(axis_sizes: Sequence[int],
                        axis_names: Sequence[str]):
-    """Device-free ``AbstractMesh`` across the constructor drift.
-
-    New JAX: ``AbstractMesh(axis_sizes, axis_names)``.
-    0.4.x:   ``AbstractMesh(shape_tuple)`` with (name, size) pairs.
-    """
+    """Device-free ``AbstractMesh`` (dry-run sharding rules)."""
     from jax.sharding import AbstractMesh
-    sizes, names = tuple(axis_sizes), tuple(axis_names)
-    try:
-        return AbstractMesh(sizes, names)
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with a manual-``Mesh`` fallback for old JAX."""
-    shapes, names = tuple(axis_shapes), tuple(axis_names)
-    maker = getattr(jax, "make_mesh", None)
-    if maker is not None:
-        return maker(shapes, names)
-    from jax.sharding import Mesh
-    n = int(np.prod(shapes))
-    devices = np.asarray(jax.devices()[:n]).reshape(shapes)
-    return Mesh(devices, names)
+    """``jax.make_mesh`` over the local devices, every axis ``Auto``."""
+    from jax.sharding import AxisType
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def to_shardings(mesh, specs):
-    """PartitionSpec pytree → NamedSharding pytree bound to ``mesh``.
-
-    Old JAX's ``jax.jit`` rejects bare ``PartitionSpec`` in
-    in_shardings/out_shardings (new JAX accepts them under an active
-    mesh); ``NamedSharding`` works everywhere, so bind unconditionally.
-    """
+    """PartitionSpec pytree → NamedSharding pytree bound to ``mesh``."""
     from jax.sharding import NamedSharding, PartitionSpec
     is_spec = lambda s: isinstance(s, PartitionSpec)
     return tree_map(lambda s: NamedSharding(mesh, s) if is_spec(s) else s,
@@ -185,65 +88,40 @@ def to_shardings(mesh, specs):
 
 
 def cost_analysis(compiled) -> dict:
-    """Flat cost dict from a compiled executable: old JAX returns a
-    one-element LIST of per-program dicts, new JAX the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """Cost dict of a compiled executable (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}
 
 
 def set_mesh(mesh):
     """Context manager activating ``mesh`` for bare-PartitionSpec
-    sharding constraints: ``jax.set_mesh`` (new) → ``use_mesh``
-    (transitional) → the legacy ``with mesh:`` resource env (0.4.x).
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    import jax.sharding as jshd
-    use_mesh = getattr(jshd, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh                       # Mesh is itself a context manager
+    sharding constraints."""
+    return jax.set_mesh(mesh)
 
 
 # ---------------------------------------------------------------------------
-# Collectives: normalize tuple-of-axis-names handling.
+# Collectives over one mesh axis name or a tuple of them.
 # ---------------------------------------------------------------------------
+
+def _axis_name(axis_names):
+    return axis_names if isinstance(axis_names, str) else tuple(axis_names)
+
 
 def axis_index(axis_names) -> jax.Array:
-    """Flattened device index over one or several mesh axes.
-
-    Newer JAX accepts a tuple directly; older versions only a single
-    name, so the row-major flattening is done by hand there.
-    """
-    if isinstance(axis_names, str):
-        return jax.lax.axis_index(axis_names)
-    axes = tuple(axis_names)
-    try:
-        return jax.lax.axis_index(axes)
-    except (TypeError, ValueError):
-        idx = jax.lax.axis_index(axes[0])
-        for a in axes[1:]:
-            idx = idx * jax.lax.psum(1, a) + jax.lax.axis_index(a)
-        return idx
+    """Flattened (row-major) device index over one or several axes."""
+    return jax.lax.axis_index(_axis_name(axis_names))
 
 
 def psum(x, axis_names):
-    return jax.lax.psum(x, tuple(axis_names)
-                        if not isinstance(axis_names, str) else axis_names)
+    return jax.lax.psum(x, _axis_name(axis_names))
 
 
 def pmax(x, axis_names):
-    return jax.lax.pmax(x, tuple(axis_names)
-                        if not isinstance(axis_names, str) else axis_names)
+    return jax.lax.pmax(x, _axis_name(axis_names))
 
 
 def all_gather(x, axis_names, *, axis: int = 0, tiled: bool = False):
-    name = tuple(axis_names) if not isinstance(axis_names, str) \
-        else axis_names
-    return jax.lax.all_gather(x, name, axis=axis, tiled=tiled)
+    return jax.lax.all_gather(x, _axis_name(axis_names), axis=axis,
+                              tiled=tiled)
 
 
 def all_gather_groups(x, axis_names, groups, *, axis: int = 0,
@@ -255,9 +133,8 @@ def all_gather_groups(x, axis_names, groups, *, axis: int = 0,
     (DESIGN.md §16): group = the devices of one host, so the gather
     rides the fast local interconnect and never crosses the network.
     """
-    name = tuple(axis_names) if not isinstance(axis_names, str) \
-        else axis_names
-    return jax.lax.all_gather(x, name, axis=axis, tiled=tiled,
+    return jax.lax.all_gather(x, _axis_name(axis_names), axis=axis,
+                              tiled=tiled,
                               axis_index_groups=[list(g) for g in groups])
 
 
@@ -272,61 +149,21 @@ def axis_size(axis_names) -> int:
 
 
 def ppermute(x, axis_names, perm):
-    """``jax.lax.ppermute`` accepting a tuple of axis names.
-
-    ``perm`` is over the row-major FLATTENED index of ``axis_names``
-    (matching :func:`axis_index`). Newer JAX takes the tuple directly;
-    on versions that reject multi-name ppermute the only shape this
-    module needs — a cyclic shift of the flattened ring — is
-    reconstructed from per-axis permutes (see :func:`ring_shift`).
-    """
-    if isinstance(axis_names, str) or len(tuple(axis_names)) == 1:
-        name = axis_names if isinstance(axis_names, str) \
-            else tuple(axis_names)[0]
-        return jax.lax.ppermute(x, name, perm)
-    return jax.lax.ppermute(x, tuple(axis_names), perm)
+    """``jax.lax.ppermute``; ``perm`` is over the flattened index of
+    ``axis_names`` (matching :func:`axis_index`)."""
+    names = _axis_name(axis_names)
+    if not isinstance(names, str) and len(names) == 1:
+        names = names[0]
+    return jax.lax.ppermute(x, names, perm)
 
 
 def ring_shift(tree: Any, axis_names) -> Any:
-    """Send each device's pytree to its flattened-ring successor.
-
-    Device ``i`` (row-major flattened index over ``axis_names``)
-    receives the value of device ``i-1 mod N`` — one stage of the
-    ring-pipelined SV shuffle. Tries the flattened multi-axis
-    ``ppermute`` first; where the installed JAX only permutes a single
-    named axis, the same ring is built from a cyclic shift on the
-    innermost axis plus a wrap-correcting shift on the outer axes:
-    only the innermost-last devices take the outer-shifted value, so
-    exactly one logical hop happens either way (at 2× wire cost on
-    those versions — correctness over bandwidth).
-    """
-    axes = tuple((axis_names,) if isinstance(axis_names, str)
-                 else axis_names)
-    n = axis_size(axes)
+    """Send each device's pytree to its flattened-ring successor:
+    device ``i`` receives the value of device ``i-1 mod N`` — one stage
+    of the ring-pipelined SV shuffle."""
+    n = axis_size(axis_names)
     perm = [(i, (i + 1) % n) for i in range(n)]
-    if len(axes) == 1:
-        return tree_map(lambda x: jax.lax.ppermute(x, axes[0], perm), tree)
-    try:
-        return tree_map(lambda x: jax.lax.ppermute(x, axes, perm), tree)
-    except (TypeError, ValueError, NotImplementedError, KeyError):
-        pass
-    # Fallback: row-major ring = inner-axis shift, plus an outer-ring
-    # shift taken only by the wrapping (inner-last → inner-first)
-    # devices. The outer correction is itself a flattened ring over the
-    # remaining axes, so the decomposition recurses until single-name
-    # ppermutes remain.
-    inner = axes[-1]
-    inner_n = jax.lax.psum(1, inner)
-    inner_perm = [(i, (i + 1) % inner_n) for i in range(inner_n)]
-    outer = axes[:-1]
-    inner_idx = jax.lax.axis_index(inner)
-
-    def shift_one(x):
-        stepped = jax.lax.ppermute(x, inner, inner_perm)
-        wrapped = ring_shift(stepped, outer)
-        return jnp.where(inner_idx == 0, wrapped, stepped)
-
-    return tree_map(shift_one, tree)
+    return tree_map(lambda x: ppermute(x, axis_names, perm), tree)
 
 
 # ---------------------------------------------------------------------------
@@ -337,31 +174,16 @@ def distributed_initialize(coordinator_address: Optional[str] = None,
                            num_processes: Optional[int] = None,
                            process_id: Optional[int] = None,
                            **kwargs) -> None:
-    """``jax.distributed.initialize`` with unsupported kwargs dropped.
-
-    The core triple (coordinator/num_processes/process_id) is stable
-    back to 0.4.x; the optional extras (``initialization_timeout``,
-    ``coordinator_bind_address``, ``cluster_detection_method``, …)
-    drifted in over the CI version matrix, so they are filtered against
-    the installed signature instead of hard-coded.
-    """
-    impl = jax.distributed.initialize
-    try:
-        params = inspect.signature(impl).parameters
-        kwargs = {k: v for k, v in kwargs.items() if k in params}
-    except (TypeError, ValueError):           # pragma: no cover
-        kwargs = {}
-    impl(coordinator_address=coordinator_address,
-         num_processes=num_processes, process_id=process_id, **kwargs)
+    """``jax.distributed.initialize`` with an explicit triple, so JAX
+    looks nothing up (no cluster auto-detection, no metadata server)."""
+    jax.distributed.initialize(coordinator_address=coordinator_address,
+                               num_processes=num_processes,
+                               process_id=process_id, **kwargs)
 
 
-def enable_cpu_collectives(impl: str = "gloo") -> bool:
+def enable_cpu_collectives(impl: str = "gloo") -> None:
     """Turn on cross-process CPU collectives (needed for any
-    multi-process run on the CPU backend; TPU/GPU ignore it). Config
-    name drift: ``jax_cpu_collectives_implementation`` (current) →
-    ``jax_cpu_enable_gloo_collectives`` (transitional) → absent (no
-    multi-process CPU support; returns False so the caller can raise a
-    readable error instead of hanging in a collective).
+    multi-process run on the CPU backend; TPU/GPU ignore it).
 
     Call ONLY on the distributed path, between
     :func:`distributed_initialize` being decided and the first backend
@@ -370,80 +192,17 @@ def enable_cpu_collectives(impl: str = "gloo") -> bool:
     program breaks backend creation outright (``distributed_client:
     NoneType``) — which is exactly why ``init_cluster``'s 1-process
     fast path never touches this."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-        return True
-    except (AttributeError, ValueError):
-        pass
-    if impl == "gloo":
-        try:
-            jax.config.update("jax_cpu_enable_gloo_collectives", True)
-            return True
-        except (AttributeError, ValueError):
-            pass
-        # 0.5+ builds gloo CPU collectives by default; a missing knob
-        # there means nothing needs enabling.
-        return jax_version() >= (0, 5, 0)
-    return False
+    jax.config.update("jax_cpu_collectives_implementation", impl)
 
 
 def make_array_from_process_local_data(sharding, local_data,
                                        global_shape: Optional[Tuple[int, ...]]
                                        = None):
-    """Assemble a global ``jax.Array`` from THIS process's shard.
-
+    """Assemble a global ``jax.Array`` from THIS process's shard:
     ``local_data`` is the concatenation (along the sharded dimension)
-    of the shards this process's addressable devices hold.  Newer JAX
-    has ``jax.make_array_from_process_local_data``; the fallback builds
-    the same array by slicing ``local_data`` per addressable device and
-    feeding ``make_array_from_single_device_arrays`` — it supports the
-    shapes this repo uses (at most ONE sharded dimension per array,
-    possibly replicated over further mesh axes).
-    """
-    maker = getattr(jax, "make_array_from_process_local_data", None)
-    if maker is not None:
-        return maker(sharding, local_data, global_shape)
-    local_data = np.asarray(local_data)
-    if global_shape is None:
-        raise ValueError("global_shape is required on JAX without "
-                         "make_array_from_process_local_data")
-    global_shape = tuple(int(s) for s in global_shape)
-    idx_map = sharding.addressable_devices_indices_map(global_shape)
-
-    def bounds(idx):
-        idx = idx if isinstance(idx, tuple) else (idx,)
-        idx = idx + (slice(None),) * (len(global_shape) - len(idx))
-        return tuple((0 if s.start is None else int(s.start),
-                      dim if s.stop is None else int(s.stop))
-                     for s, dim in zip(idx, global_shape))
-
-    uniq = sorted({bounds(i) for i in idx_map.values()})
-    varying = [k for k in range(len(global_shape))
-               if len({u[k] for u in uniq}) > 1]
-    if len(varying) > 1:
-        raise NotImplementedError(
-            "fallback assembly supports one sharded dimension, got "
-            f"{len(varying)} over shape {global_shape}")
-    dim = varying[0] if varying else 0
-    offsets = {}
-    pos = 0
-    for u in uniq:                      # unique shards, ascending offset
-        size = u[dim][1] - u[dim][0]
-        offsets[u] = (pos, size)
-        pos += size
-    if pos != local_data.shape[dim] and varying:
-        raise ValueError(
-            f"local data has {local_data.shape[dim]} rows on dim {dim} "
-            f"but this process's shards cover {pos}")
-    arrays = []
-    for dev, idx in idx_map.items():
-        start, size = offsets[bounds(idx)]
-        sel = [slice(None)] * len(global_shape)
-        if varying:
-            sel[dim] = slice(start, start + size)
-        arrays.append(jax.device_put(local_data[tuple(sel)], dev))
-    return jax.make_array_from_single_device_arrays(
-        global_shape, sharding, arrays)
+    of the shards this process's addressable devices hold."""
+    return jax.make_array_from_process_local_data(sharding, local_data,
+                                                  global_shape)
 
 
 def process_index() -> int:

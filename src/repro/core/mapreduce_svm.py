@@ -183,6 +183,18 @@ def _augment(Xl, yl, ml, sv: SVBuffer):
     return Xa, ya, ma
 
 
+def _fit_union(Xl, yl, ml, sv: SVBuffer, svm_cfg: SVMConfig,
+               params: Optional[SolverParams], vma_axes: tuple = ()):
+    """reduce phase on D_l ∪ SV_global. The SV rows are passed as the
+    solver's ``tail`` block rather than concatenated: under the
+    partition vmap a concatenation broadcasts the shared buffer to
+    every partition and copies the union, (L, per + cap, d) twice."""
+    ya = jnp.concatenate([yl, sv.y], axis=0)
+    ma = jnp.concatenate([ml, sv.mask], axis=0)
+    return fit_binary(Xl, ya, ma, svm_cfg, params=params,
+                      vma_axes=vma_axes, tail=sv.x)
+
+
 # ---------------------------------------------------------------------------
 # Functional (vmap) mode — partitions on a leading axis.
 # ---------------------------------------------------------------------------
@@ -208,8 +220,7 @@ def mapreduce_round(Xp: jax.Array, yp: jax.Array, maskp: jax.Array,
     # lifted ``p`` — fit_binary distinguishes "no override" (static
     # defaults, Pallas Gram allowed) from a traced sweep override.
     def reducer(Xl, yl, ml):
-        Xa, ya, ma = _augment(Xl, yl, ml, sv)
-        return fit_binary(Xa, ya, ma, cfg.svm, params=params)
+        return _fit_union(Xl, yl, ml, sv, cfg.svm, params)
 
     res: BinarySVM = jax.vmap(reducer)(Xp, yp, maskp)
     alpha = res.alpha                                # (L, per + cap)
@@ -432,8 +443,7 @@ def _round_candidates(Xl, yl, ml, sv: SVBuffer, cfg: MRSVMConfig,
     """
     p = cfg.svm.params() if params is None else params
     # map + reduce (original ``params``, not ``p`` — see mapreduce_round)
-    Xa, ya, ma = _augment(Xl, yl, ml, sv)
-    res = fit_binary(Xa, ya, ma, cfg.svm, params=params, vma_axes=axes)
+    res = _fit_union(Xl, yl, ml, sv, cfg.svm, params, vma_axes=axes)
     home_alpha = res.alpha[:per]
     copy_alpha = res.alpha[per:] * sv.mask
 
@@ -442,7 +452,7 @@ def _round_candidates(Xl, yl, ml, sv: SVBuffer, cfg: MRSVMConfig,
     buf_alpha = compat.pmax(copy_alpha, axes)           # (cap,)
     mine = jnp.logical_and(sv.ids >= 0, sv.ids // per == idx)
     pos = jnp.where(mine, sv.ids % per, 0)
-    folded = jnp.zeros((per,), Xl.dtype).at[pos].max(
+    folded = jnp.zeros((per,), home_alpha.dtype).at[pos].max(
         jnp.where(mine, buf_alpha, 0.0))
     home_alpha = jnp.maximum(home_alpha, folded) * ml
 
@@ -840,10 +850,8 @@ def build_sharded_round(mesh, data_axes: Sequence[str], cfg: MRSVMConfig,
     GLOBAL array sharded on its leading axis.
 
     ``check_vma=False``: every output is replicated by construction
-    (all_gather / psum results), which neither JAX 0.8's static vma
-    checker nor 0.4.x's ``check_rep`` can always infer through
-    while_loop-heavy reducers. :func:`repro.compat.shard_map` maps the
-    flag onto whichever kwarg the installed version spells.
+    (all_gather / psum results), which JAX's static vma checker cannot
+    always infer through while_loop-heavy reducers.
     """
     from jax.sharding import PartitionSpec as P
 

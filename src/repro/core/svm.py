@@ -37,11 +37,9 @@ from repro.core.kernel_fns import KernelConfig, apply_kernel
 def _pvary(tree, axes):
     """Mark a pytree as varying over shard_map manual axes (vma).
 
-    No-op when ``axes`` is empty, outside shard_map, or on a JAX with
-    no vma types at all. Needed because our while_loop carries start
-    from constants, which JAX 0.8 types as axis-invariant, while the
-    loop body outputs are device-varying. The pcast→pvary→identity
-    resolution lives in :mod:`repro.compat`.
+    No-op when ``axes`` is empty or outside shard_map. Needed because
+    our while_loop carries start from constants, which JAX types as
+    axis-invariant, while the loop body outputs are device-varying.
     """
     return compat.pvary(tree, axes)
 
@@ -149,8 +147,16 @@ def fit_binary_linear(X: jax.Array, y: jax.Array,
                       mask: Optional[jax.Array],
                       cfg: SVMConfig,
                       params: Optional[SolverParams] = None,
-                      vma_axes: tuple = ()) -> BinarySVM:
-    n, d = X.shape
+                      vma_axes: tuple = (),
+                      tail=None) -> BinarySVM:
+    """``tail`` optionally appends a second row block (the reducer's
+    SV_global copy): rows ``[0, len(X))`` come from ``X`` and the rest
+    from ``tail``. Each epoch walks the two blocks in place, in the same
+    order as over their concatenation, so the union is never copied."""
+    blocks = (X,) if tail is None else (X, tail)
+    d = X.shape[1]
+    sizes = [int(B.shape[0]) for B in blocks]
+    n = sum(sizes)
     is_sp = sparse_rows.is_sparse(X)
     p = cfg.params() if params is None else params
     # Feature rows may be bf16 (halves the dominant HBM stream, §Perf
@@ -162,10 +168,12 @@ def fit_binary_linear(X: jax.Array, y: jax.Array,
     # Q_ii = ||x_i||^2 + 1 (bias augmentation). Masked rows get 1 to avoid
     # 0-div. einsum keeps bf16 X un-materialized (no f32 copy of X).
     if is_sp:
-        qdiag = sparse_rows.row_sq_norms(X).astype(ct) + 1.0
+        qdiag = jnp.concatenate([sparse_rows.row_sq_norms(B).astype(ct)
+                                 for B in blocks]) + 1.0
     else:
-        qdiag = jnp.einsum("nd,nd->n", X, X,
-                           preferred_element_type=ct) + 1.0
+        qdiag = jnp.concatenate([
+            jnp.einsum("nd,nd->n", B, B, preferred_element_type=ct)
+            for B in blocks]) + 1.0
     qdiag = jnp.where(m > 0, qdiag, 1.0)
     C = p.C.astype(ct)
     tol = p.tol.astype(ct)
@@ -174,41 +182,49 @@ def fit_binary_linear(X: jax.Array, y: jax.Array,
     # tighten it.
     ecap = jnp.minimum(jnp.asarray(cfg.max_epochs, ct), p.max_epochs.astype(ct))
 
-    def body_i(i, carry):
-        alpha, w, b, viol = carry
-        if is_sp:
-            # sparse row i: gather w at its column ids, scatter-add the
-            # update back — O(nnz) per inner step instead of O(d)
-            ii = jax.lax.dynamic_index_in_dim(X.indices, i, keepdims=False)
-            vv = jax.lax.dynamic_index_in_dim(
-                X.values, i, keepdims=False).astype(ct)
-            wx = jnp.dot(jnp.take(w, ii), vv)
-        else:
-            xi = jax.lax.dynamic_index_in_dim(X, i, keepdims=False).astype(ct)
-            wx = jnp.dot(w, xi)
-        yi = y[i]
-        g = yi * (wx + b) - 1.0                        # ∂/∂α_i of dual obj
-        a_old = alpha[i]
-        # projected gradient for the box [0, C]
-        pg = jnp.where(a_old <= 0.0, jnp.minimum(g, 0.0),
-                       jnp.where(a_old >= C, jnp.maximum(g, 0.0), g))
-        a_new = jnp.clip(a_old - g / qdiag[i], 0.0, C)
-        delta = (a_new - a_old) * m[i]
-        alpha = alpha.at[i].set(a_old + delta)
-        if is_sp:
-            w = w.at[ii].add(delta * yi * vv)
-        else:
-            w = w + delta * yi * xi
-        b = b + delta * yi
-        viol = jnp.maximum(viol, jnp.abs(pg) * m[i])
-        return alpha, w, b, viol
+    def block_body(B, off):
+        def body_i(r, carry):
+            alpha, w, b, viol = carry
+            i = r + off                                # union row index
+            if is_sp:
+                # sparse row: gather w at its column ids, scatter-add the
+                # update back — O(nnz) per inner step instead of O(d)
+                ii = jax.lax.dynamic_index_in_dim(B.indices, r,
+                                                  keepdims=False)
+                vv = jax.lax.dynamic_index_in_dim(
+                    B.values, r, keepdims=False).astype(ct)
+                wx = jnp.dot(jnp.take(w, ii), vv)
+            else:
+                xi = jax.lax.dynamic_index_in_dim(
+                    B, r, keepdims=False).astype(ct)
+                wx = jnp.dot(w, xi)
+            yi = y[i]
+            g = yi * (wx + b) - 1.0                    # ∂/∂α_i of dual obj
+            a_old = alpha[i]
+            # projected gradient for the box [0, C]
+            pg = jnp.where(a_old <= 0.0, jnp.minimum(g, 0.0),
+                           jnp.where(a_old >= C, jnp.maximum(g, 0.0), g))
+            a_new = jnp.clip(a_old - g / qdiag[i], 0.0, C)
+            delta = (a_new - a_old) * m[i]
+            alpha = alpha.at[i].set(a_old + delta)
+            if is_sp:
+                w = w.at[ii].add(delta * yi * vv)
+            else:
+                w = w + delta * yi * xi
+            b = b + delta * yi
+            viol = jnp.maximum(viol, jnp.abs(pg) * m[i])
+            return alpha, w, b, viol
+        return body_i
 
+    offsets = [sum(sizes[:j]) for j in range(len(blocks))]
     zero = _pvary(jnp.asarray(0.0, ct), vma_axes)
 
     def epoch(carry):
         alpha, w, b, _, t = carry
-        alpha, w, b, viol = jax.lax.fori_loop(
-            0, n, body_i, (alpha, w, b, zero))
+        state = (alpha, w, b, zero)
+        for B, off, size in zip(blocks, offsets, sizes):
+            state = jax.lax.fori_loop(0, size, block_body(B, off), state)
+        alpha, w, b, viol = state
         return alpha, w, b, viol, t + 1
 
     def cond(carry):
@@ -237,14 +253,17 @@ def _pallas_gram_fn(cfg: SVMConfig, p: SolverParams) -> GramFn:
     sweeps over :class:`SolverParams` run on the Pallas path — and every
     config shares ONE compiled kernel instead of re-specializing per
     value. Only the operator choice (``kernel.name``/``degree``) stays
-    baked in at trace time."""
-    from repro.kernels import gram as gram_lib
+    baked in at trace time. The :mod:`repro.kernels.ops` wrappers pick
+    interpret mode from the backend, so a TPU never runs it
+    interpreted."""
+    from repro.kernels import ops
     kc = cfg.kernel
-    build = (gram_lib.sparse_gram if cfg.gram_impl == "pallas_sparse"
-             else gram_lib.gram)
+    build = (ops.sparse_gram_matrix if cfg.gram_impl == "pallas_sparse"
+             else ops.gram_matrix)
 
     def fn(X, Z):
-        K = build(X, Z, p.gamma, p.coef0, kind=kc.name, degree=kc.degree)
+        K = build(X, Z, gamma=p.gamma, coef0=p.coef0, kind=kc.name,
+                  degree=kc.degree)
         return K.astype(X.dtype)
     return fn
 
@@ -318,16 +337,21 @@ def fit_binary(X: jax.Array, y: jax.Array, mask: Optional[jax.Array] = None,
                cfg: SVMConfig = SVMConfig(),
                gram_fn: Optional[GramFn] = None,
                params: Optional[SolverParams] = None,
-               vma_axes: tuple = ()) -> BinarySVM:
+               vma_axes: tuple = (), tail=None) -> BinarySVM:
     """Train one reducer's soft-margin binary SVM. y ∈ {-1, +1}.
 
     ``params`` overrides the value-like hyper-params of ``cfg`` with a
     traced :class:`SolverParams` pytree (vmappable for sweeps); when
-    ``None`` the static defaults of ``cfg`` are lifted.
+    ``None`` the static defaults of ``cfg`` are lifted. ``tail`` rows
+    follow ``X`` (the training set is their union; ``y``/``mask`` cover
+    both): the linear path reads them in place, the Gram path needs
+    the union as one matrix and concatenates.
     """
     if cfg.kernel.name == "linear" and not cfg.use_gram:
         return fit_binary_linear(X, y, mask, cfg, params=params,
-                                 vma_axes=vma_axes)
+                                 vma_axes=vma_axes, tail=tail)
+    if tail is not None:
+        X = sparse_rows.rows_concat(X, tail, axis=0)
     return fit_binary_kernel(X, y, mask, cfg, gram_fn=gram_fn, params=params,
                              vma_axes=vma_axes)
 

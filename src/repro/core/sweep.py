@@ -230,8 +230,11 @@ def _run_rounds(step, svb, d: int, cfg: MRSVMConfig,
 # cache — the streaming service folds a wave per admission, and a
 # per-call ``jax.jit`` would retrace every wave (see the twin note in
 # repro.core.mapreduce_svm).
-@functools.partial(jax.jit, static_argnames=("cfg", "x_ax", "m_ax"))
-def _sweep_round_jit(Xp, ypb, maskp, sv_b, eff, cfg, x_ax, m_ax):
+@functools.partial(jax.jit, static_argnames=("cfg", "x_ax", "m_ax", "L"))
+def _sweep_round_jit(X, ypb, maskp, sv_b, eff, cfg, x_ax, m_ax, L):
+    # X stays (…, n, d) outside the program: partitioning it here keeps
+    # the caller's rows the only resident copy.
+    Xp = _partition_rows(X, L)
     out = jax.vmap(
         lambda Xq, yp, mp, sv, p: mapreduce_round(
             Xq, yp, mp, sv, cfg, params=p),
@@ -243,6 +246,14 @@ def _sweep_round_jit(Xp, ypb, maskp, sv_b, eff, cfg, x_ax, m_ax):
     w_sel = jnp.take_along_axis(out.ws, l_star[:, None, None], 1)[:, 0]
     b_sel = jnp.take_along_axis(out.bs, l_star[:, None], 1)[:, 0]
     return out.sv, r_sel, w_sel, b_sel
+
+
+def _partition_rows(X, L: int):
+    """(…, n, d) rows → (…, L, per, d), zero-padding n to L·per."""
+    n, d = X.shape[-2], X.shape[-1]
+    per = -(-n // L)
+    lead = tuple(X.shape[:-2])
+    return sparse_rows.pad_rows(X, L * per - n).reshape(*lead, L, per, d)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -273,19 +284,14 @@ def fit_mapreduce_sweep(X: jax.Array, y: jax.Array, num_partitions: int,
     L = num_partitions
     per = -(-n // L)
     pad = L * per - n
-    if X.ndim == 3:
-        if X.shape[0] != S:
-            raise ValueError(f"per-job X has leading axis {X.shape[0]}, "
-                             f"expected S={S}")
-        Xp = sparse_rows.pad_rows(X, pad).reshape(S, L, per, d)
-        x_ax = 0
-    else:
-        Xp = sparse_rows.pad_rows(X, pad).reshape(L, per, d)
-        x_ax = None
-    yb = jnp.broadcast_to(jnp.atleast_2d(y.astype(Xp.dtype)), (S, n))
+    if X.ndim == 3 and X.shape[0] != S:
+        raise ValueError(f"per-job X has leading axis {X.shape[0]}, "
+                         f"expected S={S}")
+    x_ax = 0 if X.ndim == 3 else None
+    yb = jnp.broadcast_to(jnp.atleast_2d(y.astype(X.dtype)), (S, n))
     ypb = jnp.pad(yb, ((0, 0), (0, pad))).reshape(S, L, per)
-    base_mask = (jnp.ones((n,), Xp.dtype) if mask is None
-                 else mask.astype(Xp.dtype))
+    base_mask = (jnp.ones((n,), X.dtype) if mask is None
+                 else mask.astype(X.dtype))
     if base_mask.ndim == 2:
         maskp = jnp.pad(base_mask, ((0, 0), (0, pad))).reshape(S, L, per)
         m_ax = 0
@@ -294,14 +300,14 @@ def fit_mapreduce_sweep(X: jax.Array, y: jax.Array, num_partitions: int,
         m_ax = None
 
     sv0 = init_sv_buffer(
-        cfg.sv_capacity, d, Xp.dtype,
-        nnz_cap=Xp.nnz_cap if sparse_rows.is_sparse(Xp) else None)
+        cfg.sv_capacity, d, X.dtype,
+        nnz_cap=X.nnz_cap if sparse_rows.is_sparse(X) else None)
     svb = compat.tree_map(
         lambda a: jnp.broadcast_to(a, (S,) + a.shape), sv0)
 
     def step(sv_b, eff):
-        return _sweep_round_jit(Xp, ypb, maskp, sv_b, eff,
-                                cfg=cfg, x_ax=x_ax, m_ax=m_ax)
+        return _sweep_round_jit(X, ypb, maskp, sv_b, eff,
+                                cfg=cfg, x_ax=x_ax, m_ax=m_ax, L=L)
 
     svb, best_risk, best_w, best_b, rounds, history = _run_rounds(
         step, svb, d, cfg, params, verbose, "sweep",

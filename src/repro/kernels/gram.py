@@ -111,49 +111,66 @@ def _pad_to(x: int, block: int) -> int:
 # Sparse Gram: blocked-CSR rows (ISSUE 6, gram_impl="pallas_sparse").
 # ---------------------------------------------------------------------------
 
+_SLOT_CHUNK = 8   # x-side slots per grid step (one sublane tile)
+
+
 def _sparse_gram_kernel(gamma_ref, coef0_ref, xi_ref, xv_ref, zi_ref,
                         zv_ref, rownorm_ref, colnorm_ref, o_ref, *,
-                        kind: str, degree: int, z_slots: int):
+                        kind: str, degree: int, z_slots: int,
+                        k_steps: int):
     """One (bm, bn) tile from index/value blocks (no dense (·, d) tile
-    ever exists). The contraction is an index-match accumulate: for
-    each z-side slot q, the x-side slots whose column id equals
-    ``zi[:, q]`` contribute ``xv · zv[:, q]``. Padding slots are
-    (index 0, value 0) on BOTH sides, so every spurious 0==0 match
-    multiplies a zero value — contributions vanish without masking.
+    ever exists). The contraction is an index-match accumulate: the
+    x-side slots whose column id equals z-side slot q's contribute
+    ``xv · zv[q]``. Padding slots are (index 0, value 0) on BOTH sides,
+    so every spurious 0==0 match multiplies a zero value.
     O(bm·bn·px·pz) compare-work replaces O(bm·bn·d) dense MACs: a win
     whenever nnz_cap² ≪ d (the >99%-zero TF×IDF regime this kernel
-    exists for)."""
-    xi = xi_ref[...]                              # (bm, px) int32
-    xv = xv_ref[...].astype(jnp.float32)          # (bm, px)
-    zi = zi_ref[...]                              # (bn, pz) int32
-    zv = zv_ref[...].astype(jnp.float32)          # (bn, pz)
+    exists for).
+
+    Both sides arrive slot-major — x as (_SLOT_CHUNK, bm) chunks walked
+    by grid dim 2, z as (pz, bn) — so the loop over z slots indexes the
+    sublane axis (the lane axis cannot be sliced dynamically) and each
+    step's compare tile stays (bm, bn), inside scoped VMEM at any
+    ``nnz_cap``."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    xi = jnp.transpose(xi_ref[...])               # (bm, chunk) int32
+    xv = jnp.transpose(xv_ref[...])               # (bm, chunk) f32
 
     def match_step(q, acc):
-        zq = jax.lax.dynamic_index_in_dim(zi, q, axis=1, keepdims=False)
-        vq = jax.lax.dynamic_index_in_dim(zv, q, axis=1, keepdims=False)
-        hit = xi[:, :, None] == zq[None, None, :]        # (bm, px, bn)
-        part = jnp.sum(jnp.where(hit, xv[:, :, None], 0.0), axis=1)
-        return acc + part * vq[None, :]
+        zq = zi_ref[pl.ds(q, 1), :]               # (1, bn)
+        vq = zv_ref[pl.ds(q, 1), :]
+        part = jnp.zeros(acc.shape, jnp.float32)
+        for c in range(_SLOT_CHUNK):
+            part = part + jnp.where(xi[:, c:c + 1] == zq,
+                                    xv[:, c:c + 1], 0.0)
+        return acc + part * vq
 
-    acc = jax.lax.fori_loop(
-        0, z_slots, match_step,
-        jnp.zeros(o_ref.shape, jnp.float32))
+    o_ref[...] += jax.lax.fori_loop(
+        0, z_slots, match_step, jnp.zeros(o_ref.shape, jnp.float32))
 
-    gamma = gamma_ref[0, 0]
-    coef0 = coef0_ref[0, 0]
-    if kind == "poly":
-        o_ref[...] = (gamma * acc + coef0) ** degree
-    elif kind == "rbf":
-        sq = rownorm_ref[...].T + colnorm_ref[...] - 2.0 * acc
-        o_ref[...] = jnp.exp(-gamma * jnp.maximum(sq, 0.0))
-    else:
-        o_ref[...] = acc
+    @pl.when(pl.program_id(2) == k_steps - 1)
+    def _finalize():
+        acc = o_ref[...]
+        gamma = gamma_ref[0, 0]
+        coef0 = coef0_ref[0, 0]
+        if kind == "poly":
+            o_ref[...] = (gamma * acc + coef0) ** degree
+        elif kind == "rbf":
+            sq = rownorm_ref[...].T + colnorm_ref[...] - 2.0 * acc
+            o_ref[...] = jnp.exp(-gamma * jnp.maximum(sq, 0.0))
 
 
-def _pad_sparse(sp, n_p: int):
-    pad = n_p - sp.values.shape[0]
-    return (jnp.pad(sp.indices, ((0, pad), (0, 0))),
-            jnp.pad(sp.values, ((0, pad), (0, 0))))
+def _pad_sparse(sp, n_p: int, slots: int):
+    """(rows, slots) padding of blocked-CSR rows, returned slot-major
+    (slots, rows) with f32 values."""
+    pad_r = n_p - sp.values.shape[0]
+    pad_s = slots - sp.values.shape[1]
+    idx = jnp.pad(sp.indices, ((0, pad_r), (0, pad_s)))
+    val = jnp.pad(sp.values, ((0, pad_r), (0, pad_s))).astype(jnp.float32)
+    return idx.T, val.T
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "degree", "bm", "bn",
@@ -163,10 +180,10 @@ def sparse_gram(X, Z, gamma=1.0, coef0=0.0, *, kind: str = "linear",
                 interpret: bool = True) -> jax.Array:
     """K (n, m) = k(X, Z) over blocked-CSR rows (``SparseRows``).
 
-    Both-sparse runs the Pallas index-match kernel tiled (n/bm, m/bn)
-    with each side's full (index, value) slot axis resident per tile
-    (keep ``nnz_cap`` ≲ 512 for VMEM); ``gamma``/``coef0`` ride in as
-    traced (1, 1) scalar blocks exactly like the dense kernel, so
+    Both-sparse runs the Pallas index-match kernel tiled (n/bm, m/bn,
+    nnz_cap/8): the z side's (index, value) slots are resident per
+    tile, the x side's arrive 8 at a time; ``gamma``/``coef0`` ride in
+    as traced (1, 1) scalar blocks exactly like the dense kernel, so
     SolverParams sweeps share one compiled kernel. Mixed dense×sparse
     (the serve-side decision path: dense query rows against the sparse
     SV buffer) routes through the XLA gather contraction of
@@ -189,31 +206,33 @@ def sparse_gram(X, Z, gamma=1.0, coef0=0.0, *, kind: str = "linear",
     n, m = X.values.shape[0], Z.values.shape[0]
     bm_, bn_ = min(bm, _ceil(n)), min(bn, _ceil(m))
     n_p, m_p = _pad_to(n, bm_), _pad_to(m, bn_)
-    xi, xv = _pad_sparse(X, n_p)
-    zi, zv = _pad_sparse(Z, m_p)
-    rown = jnp.sum(xv.astype(jnp.float32) ** 2, axis=1, keepdims=True)
-    coln = jnp.sum(zv.astype(jnp.float32) ** 2, axis=1, keepdims=True).T
+    px = _pad_to(X.values.shape[1], _SLOT_CHUNK)
+    xi, xv = _pad_sparse(X, n_p, px)              # (px, n_p)
+    zi, zv = _pad_sparse(Z, m_p, Z.values.shape[1])   # (pz, m_p)
+    rown = jnp.sum(xv ** 2, axis=0, keepdims=True)    # (1, n_p)
+    coln = jnp.sum(zv ** 2, axis=0, keepdims=True)    # (1, m_p)
     g = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
     c0 = jnp.asarray(coef0, jnp.float32).reshape(1, 1)
-    px, pz = xi.shape[1], zi.shape[1]
+    pz = zi.shape[0]
+    k_steps = px // _SLOT_CHUNK
 
-    scalar = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    scalar = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
     out = pl.pallas_call(
         functools.partial(_sparse_gram_kernel, kind=kind, degree=degree,
-                          z_slots=pz),
-        grid=(n_p // bm_, m_p // bn_),
+                          z_slots=pz, k_steps=k_steps),
+        grid=(n_p // bm_, m_p // bn_, k_steps),
         in_specs=[
             scalar,
             scalar,
-            pl.BlockSpec((bm_, px), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm_, px), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn_, pz), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn_, pz), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, bm_), lambda i, j: (0, i)),
-            pl.BlockSpec((1, bn_), lambda i, j: (0, j)),
+            pl.BlockSpec((_SLOT_CHUNK, bm_), lambda i, j, k: (k, i)),
+            pl.BlockSpec((_SLOT_CHUNK, bm_), lambda i, j, k: (k, i)),
+            pl.BlockSpec((pz, bn_), lambda i, j, k: (0, j)),
+            pl.BlockSpec((pz, bn_), lambda i, j, k: (0, j)),
+            pl.BlockSpec((1, bm_), lambda i, j, k: (0, i)),
+            pl.BlockSpec((1, bn_), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm_, bn_), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_p, m_p), jnp.float32),
         interpret=interpret,
-    )(g, c0, xi, xv, zi, zv, rown.T, coln)
+    )(g, c0, xi, xv, zi, zv, rown, coln)
     return out[:n, :m]

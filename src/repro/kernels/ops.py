@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret=True`` (default here) executes the kernel body in Python on
-CPU — the validation mode for this container; on real TPU hardware pass
-``interpret=False`` (the launcher does, keyed on backend).
+The one place that picks the kernels' ``interpret`` mode: compiled
+(``interpret=False``) when JAX's default backend is a TPU, interpreted
+in Python everywhere else (the CPU test runs). Callers on the solver
+path (``core/svm.py``) go through these wrappers, so a kernel never
+runs interpreted on a TPU.
 """
 from __future__ import annotations
 

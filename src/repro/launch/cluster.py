@@ -206,11 +206,8 @@ def init_cluster(cfg: Optional[ClusterConfig] = None) -> Cluster:
             (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
     platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
     if platform in ("", "cpu"):
-        if not compat.enable_cpu_collectives(cfg.cpu_collectives):
-            raise RuntimeError(
-                "this JAX has no cross-process CPU collectives "
-                f"({cfg.cpu_collectives!r}); a multi-process CPU run "
-                "would hang at the first collective")
+        compat.enable_cpu_collectives(cfg.cpu_collectives)
+
     def handshake():
         faults.maybe_raise("cluster.handshake", kinds=("handshake_flake",))
         compat.distributed_initialize(

@@ -26,13 +26,20 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.launch.cluster import (add_cluster_flags, cluster_config_from_args,
                                   init_cluster)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, simulated_hier_hosts
 from repro.launch.steps import InputShape, build_serve_step
 from repro.models.config import smoke_variant
 
 
-def serve_svm(svm_cfg, args, cluster) -> None:
+def serve_svm(svm_cfg, args, cluster) -> dict:
     """Streaming polarization serve mode (``--arch svm-tfidf``).
+
+    Each wave submits one batch per stream together (``submit_many``),
+    so with two or more streams the wave folds through the batched
+    sweep. Returns the run's record: per wave the stale and folded
+    accuracy and whether the fold was batched, plus the service's
+    throughput report.
 
     Multi-process topology: message admission runs on process 0 (the
     coordinator owns the queues and drives the folds) while model
@@ -49,7 +56,7 @@ def serve_svm(svm_cfg, args, cluster) -> None:
         svm_cfg = dc.replace(svm_cfg, num_features=128, sv_capacity=64,
                              stream_rows_per_wave=256, dtype="float32")
     d = svm_cfg.num_features
-    rows = svm_cfg.stream_rows_per_wave
+    rows = args.rows_per_wave or svm_cfg.stream_rows_per_wave
     L = args.data_par if args.data_par > 1 else 8   # partitions (default 8)
     shuffle = args.shuffle or getattr(svm_cfg, "shuffle_impl", "allgather")
     hosts = simulated_hier_hosts(L) if shuffle == "hier" else None
@@ -96,6 +103,7 @@ def serve_svm(svm_cfg, args, cluster) -> None:
             continue                   # came back with the checkpoint
         X0, y0 = batch(s, 0)
         svc.register(f"stream{s}", fit_mapreduce(X0, y0, L, cfg))
+        del X0, y0
     if not cluster.is_coordinator:
         # snapshots are served from every process; admission is not.
         acc = float(jnp.mean(svc.predict("stream0", batch(0, 0)[0])
@@ -103,20 +111,21 @@ def serve_svm(svm_cfg, args, cluster) -> None:
         print(f"process {cluster.process_index}: read-only replica "
               f"(stream0 snapshot v{svc.snapshot('stream0').version}, "
               f"acc={acc:.3f}); admission runs on process 0")
-        return
+        return {"waves": [], "replica_acc": acc}
 
     svc.start()
     # post-restore the version counters resume where the checkpoint
     # left them, so wave completion is measured against the base
     base = {s: svc.snapshot(f"stream{s}").version
             for s in range(args.streams)}
+    waves = []
     for wave in range(1, args.waves + 1):
         batches = [batch(s, wave) for s in range(args.streams)]
         stale = [float(jnp.mean(svc.predict(f"stream{s}", X) == y))
                  for s, (X, y) in enumerate(batches)]
         t0 = time.time()
-        for s, (X, y) in enumerate(batches):
-            svc.submit(f"stream{s}", X, y)
+        svc.submit_many([(f"stream{s}", X, y)
+                         for s, (X, y) in enumerate(batches)])
         deadline = time.time() + 300
         while any(svc.snapshot(f"stream{s}").version < base[s] + wave
                   for s in range(args.streams)):
@@ -126,14 +135,20 @@ def serve_svm(svm_cfg, args, cluster) -> None:
             time.sleep(0.01)
         fresh = [float(jnp.mean(svc.predict(f"stream{s}", X) == y))
                  for s, (X, y) in enumerate(batches)]
-        print(f"wave {wave}: stale acc={sum(stale)/len(stale):.3f} → "
-              f"folded acc={sum(fresh)/len(fresh):.3f} "
-              f"({time.time() - t0:.2f}s)")
+        del batches
+        wall = time.time() - t0
+        waves.append({"wave": wave, "stale": sum(stale) / len(stale),
+                      "folded": sum(fresh) / len(fresh), "wall_s": wall})
+        print(f"wave {wave}: stale acc={waves[-1]['stale']:.3f} → "
+              f"folded acc={waves[-1]['folded']:.3f} ({wall:.2f}s)")
     svc.stop()
-    print(svc.throughput_report())
+    report = svc.throughput_report()
+    print(report)
+    return {"waves": waves, "folds": [st.batched for st in svc.stats],
+            "report": report}
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -146,6 +161,9 @@ def main():
                     help="svm family: tenant streams served")
     ap.add_argument("--waves", type=int, default=3,
                     help="svm family: update waves to run")
+    ap.add_argument("--rows-per-wave", type=int, default=0,
+                    help="svm family: override the config's "
+                         "stream_rows_per_wave")
     from repro.core.mapreduce_svm import SHUFFLE_IMPLS
     ap.add_argument("--shuffle", default=None,
                     choices=SHUFFLE_IMPLS,
@@ -175,7 +193,12 @@ def main():
                     help="svm family: path of the watchdog's JSON "
                          "heartbeat file (operators poll it)")
     add_cluster_flags(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     # Before first backend use — see launch/cluster.py ordering contract.
     cluster = init_cluster(cluster_config_from_args(args))

@@ -3,9 +3,10 @@
 Builds the sharded train_step for ``--arch`` on the cluster's device
 mesh (one process, N CPU processes via --coordinator/--num-processes/
 --process-id, or the production mesh on a real TPU slice), runs the
-data pipeline, checkpoints, and logs. On this CPU container use
-``--smoke`` to train the reduced variant; the full configs are
-exercised by dryrun.py.
+data pipeline, checkpoints, and logs. Without ``--smoke`` the svm
+family trains at the config's full width (d = 131072 bf16, 8192 rows
+per device), which is what ``chip_smoke.py`` runs on a TPU; ``--smoke``
+is the reduced variant for CPU runs.
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --smoke --steps 50 --batch 8 --seq 128
@@ -34,12 +35,13 @@ from repro.configs import get_config
 from repro.data import DataConfig, lm_batch_at, svm_rows_shard
 from repro.launch.cluster import (add_cluster_flags, cluster_config_from_args,
                                   init_cluster)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, simulated_hier_hosts
 from repro.launch.steps import InputShape, build_train_step
 from repro.models.config import smoke_variant
 
 
-def train_svm(svm_cfg, args, cluster) -> None:
+def train_svm(svm_cfg, args, cluster, rows=None) -> dict:
     """MapReduce-SVM training mode (``--arch svm-tfidf``): rows sharded
     over the data mesh, rounds driven on the host. ``--sweep S`` runs S
     (C, γ) hyper-parameter configs per round as one batched program —
@@ -48,7 +50,11 @@ def train_svm(svm_cfg, args, cluster) -> None:
     Process-count-agnostic (DESIGN.md §11): each process loads only its
     disjoint TF×IDF row shard (``svm_rows_shard``) and assembles the
     global arrays via ``cluster.make_global_array``; the sharded round
-    itself is the SAME program at any process count.
+    itself is the SAME program at any process count. ``rows`` passes in
+    an already generated ``(X, y)`` shard, so two runs can share data.
+
+    Returns the run's record: per-round ``R_emp`` and ``|SV|``, and the
+    accuracy of the selected hypothesis.
     """
     import dataclasses as dc
 
@@ -76,9 +82,9 @@ def train_svm(svm_cfg, args, cluster) -> None:
                                     max_epochs=svm_cfg.max_epochs))
 
     dt = jnp.dtype(svm_cfg.dtype)
-    Xl, yl = svm_rows_shard(n, d, seed=0,
-                            process_index=cluster.process_index,
-                            process_count=cluster.process_count)
+    Xl, yl = rows if rows is not None else svm_rows_shard(
+        n, d, seed=0, process_index=cluster.process_index,
+        process_count=cluster.process_count)
     X = cluster.make_global_array(mesh, P("data"), Xl.astype(dt), (n, d))
     y = cluster.make_global_array(mesh, P("data"), yl.astype(dt), (n,))
     say(f"svm-tfidf: {n} rows × {d} features over {ndev} devices, "
@@ -110,25 +116,37 @@ def train_svm(svm_cfg, args, cluster) -> None:
                 f"rounds={int(out.rounds[s])}")
         say(f"sweep selected C={float(params.C[out.best]):.4g} "
             f"({args.sweep} configs, one jit, {dt_s:.1f}s)")
-        return
+        return {"risks": [float(r) for r in out.risks],
+                "accuracy": [float(a) for a in accs]}
 
     round_fn = build_sharded_round(mesh, ("data",), cfg, per)
     sv = init_sv_buffer(cfg.sv_capacity, d, X.dtype)
     mask = cluster.make_global_array(
         mesh, P("data"), np.ones((Xl.shape[0],), Xl.dtype).astype(dt), (n,))
     prev = float("inf")
+    history = []
+    best = (float("inf"), None, None)    # keep the best h^t, as fit_mapreduce
     for t in range(rounds):
         sv, risks, w, b = round_fn(X, y, mask, sv)
         r = float(jnp.min(risks))
-        say(f"round {t}: R_emp={r:.4f} |SV|={int(jnp.sum(sv.mask))}")
+        if r < best[0]:
+            best = (r, w, b)
+        n_sv = int(jnp.sum(sv.mask))
+        history.append({"round": t, "risk": r, "sv": n_sv,
+                        "ids": np.asarray(sv.ids)})
+        say(f"round {t}: R_emp={r:.4f} |SV|={n_sv}")
         if t > 0 and abs(prev - r) <= cfg.gamma:
             break
         prev = r
-    say(f"best-reducer accuracy: {float(local_acc(w, b)):.3f}"
+    acc = float(local_acc(best[1], best[2]))
+    say(f"best-reducer accuracy: {acc:.3f}"
         + (" (host-local shard)" if cluster.is_distributed else ""))
+    return {"rounds": history, "accuracy": acc, "shuffle": shuffle,
+            "shardings": {"X": X.sharding, "sv.x": sv.x.sharding,
+                          "risks": risks.sharding, "w": w.sharding}}
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -152,7 +170,12 @@ def main():
                     help="svm family: SV merge transport (default: the "
                          "arch config's shuffle_impl)")
     add_cluster_flags(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     # BEFORE anything touches a device: the distributed client and the
     # CPU collectives wire into the backend at first init (DESIGN.md §11).

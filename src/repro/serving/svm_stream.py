@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import threading
@@ -83,6 +84,19 @@ def _all_finite(X, y) -> bool:
     vals = X.values if sparse_rows.is_sparse(X) else X
     return bool(np.isfinite(np.asarray(vals)).all()
                 and np.isfinite(np.asarray(y)).all())
+
+
+@functools.partial(jax.jit, static_argnames=("n_max", "width"))
+def _stack_jobs(new_rows, sv_rows, n_max: int, width: int):
+    """The batched fold's (width, n_max, d) rows in one program: each
+    job's new rows ∪ its carried SVs, zero-padded to ``n_max``, then
+    all-empty padding jobs up to ``width``. Built op by op, every
+    per-job concatenation would stay alive beside the stack."""
+    parts = [sparse_rows.pad_rows(sparse_rows.rows_concat(x, sv, axis=0),
+                                  n_max - x.shape[0] - sv.shape[0])
+             for x, sv in zip(new_rows, sv_rows)]
+    parts += [sparse_rows.rows_zeros_like(parts[0])] * (width - len(parts))
+    return sparse_rows.rows_stack(parts)
 
 
 def _snapshot_tree(snap: "ModelSnapshot") -> dict:
@@ -540,7 +554,13 @@ class StreamingSVMService:
     def submit(self, stream: str, X: jax.Array, y: jax.Array) -> int:
         """Queue one vectorized micro-batch; returns its uid. ``X`` is
         dense ``(n, d)`` or blocked-CSR :class:`repro.sparse.SparseRows`
-        — whichever format the stream's model serves.
+        — whichever format the stream's model serves."""
+        return self.submit_many([(stream, X, y)])[0]
+
+    def submit_many(self, batches) -> List[int]:
+        """Queue ``(stream, X, y)`` micro-batches under one hold of the
+        queue lock, so the scheduler admits them in the same wave (and
+        several streams fold together). Returns the uids.
 
         Admission is coordinator-only on a multi-process cluster: a
         submit on any other process is a routing bug (its queue would
@@ -559,6 +579,13 @@ class StreamingSVMService:
                 f"{self.cluster.process_index} of "
                 f"{self.cluster.process_count} (snapshots stay readable "
                 "here — route submissions to the coordinator)")
+        with self._cv:
+            uids = [self._enqueue(stream, X, y) for stream, X, y in batches]
+            self._cv.notify_all()
+        return uids
+
+    def _enqueue(self, stream: str, X, y) -> int:
+        """Validate and queue one micro-batch; the caller holds ``_cv``."""
         # featurizer seam: an armed poison_rows fault lands NaN/Inf in
         # the batch exactly where a buggy upstream vectorizer would
         spec = faults.fire("serving.submit", kinds=("poison_rows",))
@@ -570,61 +597,59 @@ class StreamingSVMService:
         if X.ndim != 2 or y.shape[0] != X.shape[0]:
             raise ValueError(f"micro-batch must be (n, d) rows with (n,) "
                              f"labels; got X{X.shape} y{y.shape}")
-        with self._cv:
-            if stream not in self._snapshots:
-                raise KeyError(f"unregistered stream {stream!r}")
-            sv_x = self._snapshots[stream].model.sv.x
-            d_model = sv_x.shape[1]
-            if X.shape[1] != d_model:
-                raise ValueError(
-                    f"stream {stream!r} serves {d_model}-dim features but "
-                    f"the batch has {X.shape[1]} — vectorize with the same "
-                    "featurizer as training")
-            sp_model = sparse_rows.is_sparse(sv_x)
-            sp_batch = sparse_rows.is_sparse(X)
-            if sp_model != sp_batch:
-                raise ValueError(
-                    f"stream {stream!r} serves "
-                    f"{'sparse' if sp_model else 'dense'} rows but the "
-                    f"batch is {'sparse' if sp_batch else 'dense'} — "
-                    "submit the model's row format")
-            if sp_batch and X.nnz_cap != sv_x.nnz_cap:
-                raise ValueError(
-                    f"stream {stream!r} serves nnz_cap={sv_x.nnz_cap} "
-                    f"rows but the batch has nnz_cap={X.nnz_cap} — "
-                    "re-block with the model's cap")
-            if self.quarantine and not _all_finite(X, y):
-                # NaN/Inf never reaches a fold: one poisoned row in
-                # SV_global would corrupt every later wave's model.
-                # The batch is acknowledged (uid) but diverted —
-                # counted in throughput_report for the operator.
-                faults.count("quarantined")
-                self._uid += 1
-                mb = MicroBatch(uid=self._uid, stream=stream,
-                                X=None, y=None,
-                                submitted_s=time.time())
-                self.quarantined.append(mb)
-                return mb.uid
-            q = self._queues[stream]
-            if (self.max_queue_per_stream is not None
-                    and len(q) >= self.max_queue_per_stream):
-                if self.shed_policy == "reject":
-                    raise RuntimeError(
-                        f"stream {stream!r} queue is at its cap "
-                        f"({self.max_queue_per_stream}) — admission "
-                        "control rejected the batch (shed_policy="
-                        "'reject')")
-                # drop_oldest: the stalest queued batch is the least
-                # valuable under drift — shed it, keep the fresh one
-                old = q.pop(0)
-                old.X = old.y = None
-                self.shed.append(old)
+        if stream not in self._snapshots:
+            raise KeyError(f"unregistered stream {stream!r}")
+        sv_x = self._snapshots[stream].model.sv.x
+        d_model = sv_x.shape[1]
+        if X.shape[1] != d_model:
+            raise ValueError(
+                f"stream {stream!r} serves {d_model}-dim features but "
+                f"the batch has {X.shape[1]} — vectorize with the same "
+                "featurizer as training")
+        sp_model = sparse_rows.is_sparse(sv_x)
+        sp_batch = sparse_rows.is_sparse(X)
+        if sp_model != sp_batch:
+            raise ValueError(
+                f"stream {stream!r} serves "
+                f"{'sparse' if sp_model else 'dense'} rows but the "
+                f"batch is {'sparse' if sp_batch else 'dense'} — "
+                "submit the model's row format")
+        if sp_batch and X.nnz_cap != sv_x.nnz_cap:
+            raise ValueError(
+                f"stream {stream!r} serves nnz_cap={sv_x.nnz_cap} "
+                f"rows but the batch has nnz_cap={X.nnz_cap} — "
+                "re-block with the model's cap")
+        if self.quarantine and not _all_finite(X, y):
+            # NaN/Inf never reaches a fold: one poisoned row in
+            # SV_global would corrupt every later wave's model.
+            # The batch is acknowledged (uid) but diverted —
+            # counted in throughput_report for the operator.
+            faults.count("quarantined")
             self._uid += 1
-            mb = MicroBatch(uid=self._uid, stream=stream, X=X, y=y,
+            mb = MicroBatch(uid=self._uid, stream=stream,
+                            X=None, y=None,
                             submitted_s=time.time())
-            self._queues[stream].append(mb)
-            self._cv.notify_all()
+            self.quarantined.append(mb)
             return mb.uid
+        q = self._queues[stream]
+        if (self.max_queue_per_stream is not None
+                and len(q) >= self.max_queue_per_stream):
+            if self.shed_policy == "reject":
+                raise RuntimeError(
+                    f"stream {stream!r} queue is at its cap "
+                    f"({self.max_queue_per_stream}) — admission "
+                    "control rejected the batch (shed_policy="
+                    "'reject')")
+            # drop_oldest: the stalest queued batch is the least
+            # valuable under drift — shed it, keep the fresh one
+            old = q.pop(0)
+            old.X = old.y = None
+            self.shed.append(old)
+        self._uid += 1
+        mb = MicroBatch(uid=self._uid, stream=stream, X=X, y=y,
+                        submitted_s=time.time())
+        self._queues[stream].append(mb)
+        return mb.uid
 
     def pending(self) -> int:
         with self._lock:
@@ -878,15 +903,17 @@ class StreamingSVMService:
         d = joined[names[0]][0].model.sv.x.shape[1]
         n_max = max(int(joined[s][2].shape[0]) for s in names) + cap
 
-        Xs, ys, ms, ps = [], [], [], []
+        width = self._bucket_width(len(names))
+        Xb = _stack_jobs(tuple(joined[s][2] for s in names),
+                         tuple(joined[s][0].model.sv.x for s in names),
+                         n_max=n_max, width=width)   # (S', n_max, d)
+        ys, ms, ps = [], [], []
         for s in names:
             snap, _, Xn, yn = joined[s]
             sv = snap.model.sv
             n_new = int(Xn.shape[0])
             pad = n_max - n_new - cap
             dt = yn.dtype
-            Xs.append(sparse_rows.pad_rows(
-                sparse_rows.rows_concat(Xn, sv.x, axis=0), pad))
             ys.append(jnp.concatenate(
                 [yn, sv.y.astype(dt), jnp.zeros((pad,), dt)], axis=0))
             ms.append(jnp.concatenate(
@@ -897,12 +924,10 @@ class StreamingSVMService:
         # Elastic job axis: pad to the bucket width with all-masked
         # zero jobs (their results are discarded below) so a wave of
         # any tenant count reuses the bucket's compiled program.
-        for _ in range(self._bucket_width(len(names)) - len(names)):
-            Xs.append(sparse_rows.rows_zeros_like(Xs[0]))
+        for _ in range(width - len(names)):
             ys.append(jnp.zeros_like(ys[0]))
             ms.append(jnp.zeros_like(ms[0]))
             ps.append(ps[0])
-        Xb = sparse_rows.rows_stack(Xs)          # (S', n_max, d)
         yb = jnp.stack(ys)                       # (S', n_max)
         mb_ = jnp.stack(ms)                      # (S', n_max)
         params_b = stack_params(ps)

@@ -13,9 +13,11 @@ def pytest_configure(config):
 
 def subprocess_env(**overrides):
     """Minimal env for subprocess-based tests (fake-device runs need a
-    fresh backend init). JAX_PLATFORMS must survive into the child:
-    without it jax probes the baked-in libtpu and hangs retrying TPU
-    metadata — these forced-host-device runs are cpu by construction."""
+    fresh backend init). The tests run on the CPU backend
+    (``JAX_PLATFORMS=cpu``, faked devices via XLA_FLAGS), and the child
+    keeps that platform: these forced-host-device runs are CPU by
+    construction. On a TPU the system runs through ``chip_smoke.py``,
+    in one process."""
     env = {"PYTHONPATH": "src",
            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
            "HOME": os.environ.get("HOME", "/root"),

@@ -1,7 +1,7 @@
 """Cluster-runtime regressions (ISSUE 5 satellites): the 1-process
 fast path must stay a no-op (no coordinator handshake), and
 make_global_array must round-trip against plain ``jax.device_put`` on
-a single host — on both the native assembly and the compat fallback.
+a single host.
 The real multi-process behaviour is tests/test_multihost.py."""
 import argparse
 
@@ -97,21 +97,6 @@ def test_make_global_array_roundtrips_against_device_put():
     _roundtrip(P("data"), rows, rows.shape)
     _roundtrip(P(), rows, rows.shape)                      # replicated
     _roundtrip(P("data"), np.arange(2 * n, dtype=np.int32), (2 * n,))
-
-
-def test_make_global_array_fallback_single_device_arrays(monkeypatch):
-    """Old-JAX path: without jax.make_array_from_process_local_data the
-    compat fallback assembles the same array per device."""
-    monkeypatch.delattr(jax, "make_array_from_process_local_data",
-                        raising=False)
-    n = len(jax.devices())
-    rows = np.arange(4 * n * 2, dtype=np.float32).reshape(4 * n, 2)
-    _roundtrip(P("data"), rows, rows.shape)
-    _roundtrip(P(), rows, rows.shape)
-    # fallback needs the explicit global shape
-    with pytest.raises(ValueError, match="global_shape"):
-        local_cluster().make_global_array(
-            make_host_mesh(n, 1), P("data"), rows, None)
 
 
 def test_make_cluster_mesh_process_order():

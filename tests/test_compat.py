@@ -1,4 +1,4 @@
-"""Unit tests for the version-portable JAX substrate (repro.compat)."""
+"""Unit tests for the JAX substrate (repro.compat)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,10 +22,7 @@ def test_tree_map():
 
 
 # ---------------------------------------------------------------------------
-# pvary: the _pvary regression (ISSUE 1 satellite). On JAX without
-# pcast/pvary the old fallback raised AttributeError from inside the
-# except block whenever vma_axes was non-empty; it must degrade to the
-# identity instead.
+# pvary: a no-op on values, inside shard_map or outside it.
 # ---------------------------------------------------------------------------
 
 def test_pvary_empty_axes_is_identity():
@@ -35,7 +32,7 @@ def test_pvary_empty_axes_is_identity():
 
 def test_pvary_nonempty_axes_never_raises():
     tree = (jnp.zeros((4,)), jnp.asarray(1.0))
-    out = compat.pvary(tree, ("data",))     # outside shard_map, old JAX
+    out = compat.pvary(tree, ("data",))     # outside shard_map
     for a, b in zip(jax.tree_util.tree_leaves(tree),
                     jax.tree_util.tree_leaves(out)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -43,8 +40,7 @@ def test_pvary_nonempty_axes_never_raises():
 
 def test_svm_pvary_shim_and_vma_axes_path():
     """fit_binary with non-empty vma_axes (the sharded reducer call
-    signature) must run on the installed JAX — this is exactly the
-    configuration that used to die in _pvary's except block."""
+    signature) must run outside shard_map too."""
     from repro.core.svm import SVMConfig, _pvary, fit_binary
     x = {"a": jnp.ones((2, 2))}
     out = _pvary(x, ("data",))
@@ -58,7 +54,7 @@ def test_svm_pvary_shim_and_vma_axes_path():
 
 
 # ---------------------------------------------------------------------------
-# Mesh construction across the constructor drift.
+# Mesh construction.
 # ---------------------------------------------------------------------------
 
 def test_make_abstract_mesh():
@@ -79,7 +75,7 @@ def test_make_mesh_local_devices():
 
 
 # ---------------------------------------------------------------------------
-# shard_map wrapper: check_vma mapping + collectives on the installed JAX.
+# shard_map wrapper: check_vma passthrough + collectives.
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("check_vma", [None, False])
@@ -125,26 +121,6 @@ def test_ring_shift_single_device_identity():
     a, b = jax.jit(fn)(jnp.arange(4.0))
     np.testing.assert_array_equal(np.asarray(a), np.arange(4.0))
     np.testing.assert_array_equal(np.asarray(b), 2.0 * np.arange(4.0))
-
-
-def test_ring_shift_multi_axis_fallback(monkeypatch):
-    """Where jax.lax.ppermute rejects a tuple of axis names, ring_shift
-    must rebuild the flattened ring from per-axis permutes (inner shift
-    + wrap-correcting outer shift) instead of failing."""
-    orig = jax.lax.ppermute
-
-    def single_axis_only(x, axis_name, perm):
-        if not isinstance(axis_name, str):
-            raise TypeError("tuple axis names unsupported (old JAX)")
-        return orig(x, axis_name, perm)
-
-    monkeypatch.setattr(jax.lax, "ppermute", single_axis_only)
-    mesh = compat.make_mesh((1, 1), ("a", "b"))
-    fn = compat.shard_map(lambda x: compat.ring_shift(x, ("a", "b")),
-                          mesh=mesh, in_specs=(P(),), out_specs=P(),
-                          check_vma=False)
-    out = jax.jit(fn)(jnp.arange(3.0))
-    np.testing.assert_array_equal(np.asarray(out), np.arange(3.0))
 
 
 def test_ppermute_single_axis():

@@ -160,28 +160,6 @@ def _check_ring_pod_2d():
                               shuffle_impl="ring")
 
 
-def _check_ring_fallback_pod_2d():
-    """Old-JAX decomposition path: force single-axis-only ppermute so
-    compat.ring_shift rebuilds the flattened ("pod","data") ring from
-    the inner shift + wrap-correcting outer shift, and re-run the full
-    pod-mesh ring equivalence against the functional oracle — the
-    1×1-mesh unit test can't catch a misrouted wrap."""
-    import jax.lax as _lax
-    orig = _lax.ppermute
-
-    def single_axis_only(x, axis_name, perm):
-        if not isinstance(axis_name, str):
-            raise TypeError("tuple axis names unsupported (forced)")
-        return orig(x, axis_name, perm)
-
-    _lax.ppermute = single_axis_only
-    try:
-        _assert_round_equivalence((2, NDEV // 2), ("pod", "data"),
-                                  shuffle_impl="ring")
-    finally:
-        _lax.ppermute = orig
-
-
 def _check_ring_bf16_wire(rounds=3, shuffle_impl="ring",
                           hier_num_hosts=None):
     """The production wire dtype: with bf16-representable rows the wire
@@ -470,13 +448,6 @@ def test_sparse_hier_round_matches_dense_functional():
         _check_sparse_hier_1d()
     else:
         _in_subprocess("_check_sparse_hier_1d")
-
-
-def test_ring_round_single_axis_ppermute_fallback():
-    if len(jax.devices()) >= NDEV:
-        _check_ring_fallback_pod_2d()
-    else:
-        _in_subprocess("_check_ring_fallback_pod_2d")
 
 
 def test_sparse_round_matches_dense_functional():
