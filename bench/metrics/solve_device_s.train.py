@@ -1,0 +1,21 @@
+"""Device seconds of the dual-CD solver per fit: the union of the
+intervals of the device ops traced under the program's ``svm.solve``
+scope (every round's local solves and the final solve; a ``while`` op
+holds its body's ops, so they are not summed), clipped to the traced
+window, over the fits traced, one ``_final_fit_jit`` execution each."""
+from pathlib import Path
+
+from bench import trace as trace_lib
+from bench.metrics import _scopes
+
+CHECKOUT = Path(__file__).resolve().parents[2]
+
+
+def read(run):
+    if run.trace is None or not run.trace.devices:
+        return None
+    ops = _scopes.device_ops(run, CHECKOUT)
+    secs = _scopes.scope_seconds(ops, "svm.solve", run.trace.window)
+    runs = sum(v for k, v in trace_lib.program_runs(run.trace).items()
+               if "_final_fit_jit" in k)
+    return secs / runs if runs and secs else None

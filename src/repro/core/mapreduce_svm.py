@@ -224,48 +224,55 @@ def mapreduce_round(Xp: jax.Array, yp: jax.Array, maskp: jax.Array,
 
     res: BinarySVM = jax.vmap(reducer)(Xp, yp, maskp)
     alpha = res.alpha                                # (L, per + cap)
-    home_alpha = alpha[:, :per].reshape(-1)          # (L*per,) by global id
-    copy_alpha = alpha[:, per:]                      # (L, cap) appended copies
 
-    # --- union semantics: α_eff(row) = max over all copies ------------------
-    buf_alpha = jnp.max(copy_alpha, axis=0) * sv.mask          # (cap,)
-    safe_ids = jnp.where(sv.ids >= 0, sv.ids, 0)
-    folded = jnp.zeros_like(home_alpha).at[safe_ids].max(
-        jnp.where(sv.ids >= 0, buf_alpha, 0.0))
-    home_alpha = jnp.maximum(home_alpha, folded).reshape(L, per) * maskp
+    with jax.named_scope("mr.merge"):
+        home_alpha = alpha[:, :per].reshape(-1)      # (L*per,) by global id
+        copy_alpha = alpha[:, per:]                  # (L, cap) appended copies
 
-    # --- merge: balanced top-k per partition, concatenated -------------------
-    topv, topi = jax.lax.top_k(home_alpha, k)                   # (L, k)
-    sel = lambda A: jnp.take_along_axis(A, topi, axis=1)
-    new_x = sparse_rows.take_rows_along(Xp, topi).reshape(cap, d)
-    new_y = sel(yp).reshape(cap)
-    live = (topv > p.sv_threshold).astype(Xp.dtype)
-    base_ids = (jnp.arange(L, dtype=jnp.int32) * per)[:, None] + topi.astype(jnp.int32)
-    new_sv = SVBuffer(
-        x=new_x * live.reshape(cap, 1),
-        y=new_y * live.reshape(cap),
-        alpha=(topv * live).reshape(cap),
-        ids=jnp.where(live.reshape(cap) > 0, base_ids.reshape(cap), -1),
-        mask=live.reshape(cap),
-    )
+        # --- union semantics: α_eff(row) = max over all copies --------------
+        buf_alpha = jnp.max(copy_alpha, axis=0) * sv.mask          # (cap,)
+        safe_ids = jnp.where(sv.ids >= 0, sv.ids, 0)
+        folded = jnp.zeros_like(home_alpha).at[safe_ids].max(
+            jnp.where(sv.ids >= 0, buf_alpha, 0.0))
+        home_alpha = jnp.maximum(home_alpha, folded).reshape(L, per) * maskp
+
+        # --- merge: balanced top-k per partition, concatenated ---------------
+        topv, topi = jax.lax.top_k(home_alpha, k)                   # (L, k)
+        sel = lambda A: jnp.take_along_axis(A, topi, axis=1)
+        new_x = sparse_rows.take_rows_along(Xp, topi).reshape(cap, d)
+        new_y = sel(yp).reshape(cap)
+        live = (topv > p.sv_threshold).astype(Xp.dtype)
+        base_ids = ((jnp.arange(L, dtype=jnp.int32) * per)[:, None]
+                    + topi.astype(jnp.int32))
+        new_sv = SVBuffer(
+            x=new_x * live.reshape(cap, 1),
+            y=new_y * live.reshape(cap),
+            alpha=(topv * live).reshape(cap),
+            ids=jnp.where(live.reshape(cap) > 0, base_ids.reshape(cap), -1),
+            mask=live.reshape(cap),
+        )
 
     # --- driver: risk of every reducer hypothesis on the FULL data (eq. 7) --
-    Xflat = Xp.reshape(L * per, d)
-    yflat = yp.reshape(L * per)
-    mflat = maskp.reshape(L * per)
-    if cfg.svm.kernel.name == "linear" and not cfg.svm.use_gram:
-        scores = Xflat @ res.w.T + res.b[None, :]               # (n, L)
-        risks = jax.vmap(
-            lambda s: risk_lib.empirical_risk(s, yflat, mflat, cfg.risk_loss),
-            in_axes=1)(scores)
-    else:
-        def risk_of(Xa, ya, ma, a, b):
-            coef = a * ya * ma
-            s = decision_kernel(Xa, coef, b, Xflat, cfg.svm.kernel,
-                                gamma=p.gamma, coef0=p.coef0)
-            return risk_lib.empirical_risk(s, yflat, mflat, cfg.risk_loss)
-        Xa, ya, ma = jax.vmap(lambda X, y, m: _augment(X, y, m, sv))(Xp, yp, maskp)
-        risks = jax.vmap(risk_of)(Xa, ya, ma, alpha, res.b)
+    with jax.named_scope("mr.score"):
+        Xflat = Xp.reshape(L * per, d)
+        yflat = yp.reshape(L * per)
+        mflat = maskp.reshape(L * per)
+        if cfg.svm.kernel.name == "linear" and not cfg.svm.use_gram:
+            scores = Xflat @ res.w.T + res.b[None, :]               # (n, L)
+            risks = jax.vmap(
+                lambda s: risk_lib.empirical_risk(s, yflat, mflat,
+                                                  cfg.risk_loss),
+                in_axes=1)(scores)
+        else:
+            def risk_of(Xa, ya, ma, a, b):
+                coef = a * ya * ma
+                s = decision_kernel(Xa, coef, b, Xflat, cfg.svm.kernel,
+                                    gamma=p.gamma, coef0=p.coef0)
+                return risk_lib.empirical_risk(s, yflat, mflat,
+                                               cfg.risk_loss)
+            Xa, ya, ma = jax.vmap(lambda X, y, m: _augment(X, y, m, sv))(
+                Xp, yp, maskp)
+            risks = jax.vmap(risk_of)(Xa, ya, ma, alpha, res.b)
     return RoundResult(sv=new_sv, risks=risks, ws=res.w, bs=res.b,
                        sv_count=jnp.sum(new_sv.mask))
 
@@ -308,67 +315,76 @@ def fit_mapreduce(X: jax.Array, y: jax.Array, num_partitions: int,
     the host until eq. 8 fires or ``max_rounds`` is hit. ``params``
     optionally overrides the value-like solver hyper-params (traced).
     """
-    n, d = X.shape
-    L = num_partitions
-    per = -(-n // L)
-    pad = L * per - n
-    Xp = sparse_rows.pad_rows(X, pad).reshape(L, per, d)
-    yp = jnp.pad(y.astype(X.dtype), (0, pad)).reshape(L, per)
-    base_mask = jnp.ones((n,), X.dtype) if mask is None else mask.astype(X.dtype)
-    maskp = jnp.pad(base_mask, (0, pad)).reshape(L, per)
+    with jax.profiler.TraceAnnotation("mr.fit"):
+        n, d = X.shape
+        L = num_partitions
+        per = -(-n // L)
+        pad = L * per - n
+        Xp = sparse_rows.pad_rows(X, pad).reshape(L, per, d)
+        yp = jnp.pad(y.astype(X.dtype), (0, pad)).reshape(L, per)
+        base_mask = (jnp.ones((n,), X.dtype) if mask is None
+                     else mask.astype(X.dtype))
+        maskp = jnp.pad(base_mask, (0, pad)).reshape(L, per)
 
-    sv = init_sv_buffer(
-        cfg.sv_capacity, d, X.dtype,
-        nnz_cap=X.nnz_cap if sparse_rows.is_sparse(X) else None)
+        sv = init_sv_buffer(
+            cfg.sv_capacity, d, X.dtype,
+            nnz_cap=X.nnz_cap if sparse_rows.is_sparse(X) else None)
 
-    best = (np.inf, None, None)
-    prev_risk = np.inf
-    history = []
-    rounds_done = 0
-    for t in range(cfg.max_rounds):
-        # transport seams (DESIGN.md §15): a delayed round completes
-        # late but EXACTLY (survived bit-for-bit); a transiently failing
-        # merge is retried with backoff — only the injected
-        # TransientFault retries, real solver errors surface at once.
-        faults.maybe_sleep("transport.round", when=t)
+        best = (np.inf, None, None)
+        prev_risk = np.inf
+        history = []
+        rounds_done = 0
+        for t in range(cfg.max_rounds):
+            with jax.profiler.TraceAnnotation("mr.round", round=t):
+                # transport seams (DESIGN.md §15): a delayed round
+                # completes late but EXACTLY (survived bit-for-bit); a
+                # transiently failing merge is retried with backoff —
+                # only the injected TransientFault retries, real solver
+                # errors surface at once.
+                faults.maybe_sleep("transport.round", when=t)
 
-        def run_round():
-            faults.maybe_raise("transport.merge",
-                               kinds=("transport_exc",), when=t)
-            return _round_jit(Xp, yp, maskp, sv, params, cfg=cfg)
+                def run_round():
+                    faults.maybe_raise("transport.merge",
+                                       kinds=("transport_exc",), when=t)
+                    return _round_jit(Xp, yp, maskp, sv, params, cfg=cfg)
 
-        out = faults.retry_with_backoff(
-            run_round, attempts=3, base_s=0.05,
-            retry_on=faults.TransientFault, layer="transport",
-            cause=f"merge collective at round {t}",
-            action="check inter-host links; a persistent failure means "
-                   "the mesh lost a member — restart from the last "
-                   "checkpoint")
-        sv = out.sv
-        # eq. 8's designed device→host sync point: sanctioned for the
-        # host-sync lint (DESIGN.md §14) by name, right where it happens.
-        with allowed_host_sync("eq. 8 risk readback"):
-            risks = np.asarray(out.risks)
-        faults.check_finite_risks(risks, where=f"mapreduce round {t}")
-        l_star = int(np.argmin(risks))
-        r_star = float(risks[l_star])
-        if r_star < best[0]:
-            best = (r_star, out.ws[l_star], out.bs[l_star])
-        history.append({"round": t, "risk": r_star, "reducer": l_star,
-                        "sv_count": int(out.sv_count)})
-        rounds_done = t + 1
-        if verbose:
-            print(f"[mapreduce-svm] round={t} R_emp={r_star:.5f} "
-                  f"|SV|={int(out.sv_count)}")
-        if t > 0 and abs(prev_risk - r_star) <= cfg.gamma:   # eq. 8
-            break
-        prev_risk = r_star
+                out = faults.retry_with_backoff(
+                    run_round, attempts=3, base_s=0.05,
+                    retry_on=faults.TransientFault, layer="transport",
+                    cause=f"merge collective at round {t}",
+                    action="check inter-host links; a persistent failure "
+                           "means the mesh lost a member — restart from "
+                           "the last checkpoint")
+                sv = out.sv
+                # eq. 8's designed device→host sync point, the round's
+                # only one: sanctioned for the host-sync lint (DESIGN.md
+                # §14) by name, right where it happens.
+                with allowed_host_sync("eq. 8 risk readback"), \
+                        jax.profiler.TraceAnnotation("mr.eq8", round=t):
+                    risks, sv_count = jax.device_get(
+                        (out.risks, out.sv_count))
+                faults.check_finite_risks(risks, where=f"mapreduce round {t}")
+                l_star = int(np.argmin(risks))
+                r_star = float(risks[l_star])
+                if r_star < best[0]:
+                    best = (r_star, out.ws[l_star], out.bs[l_star])
+                history.append({"round": t, "risk": r_star, "reducer": l_star,
+                                "sv_count": int(sv_count)})
+                rounds_done = t + 1
+                if verbose:
+                    print(f"[mapreduce-svm] round={t} R_emp={r_star:.5f} "
+                          f"|SV|={int(sv_count)}")
+                if t > 0 and abs(prev_risk - r_star) <= cfg.gamma:   # eq. 8
+                    break
+                prev_risk = r_star
 
-    # Final consolidated model: retrain on SV_global alone (cascade-style).
-    final = _final_fit_jit(sv, params, cfg=cfg)
-    return MapReduceSVM(w=best[1], b=best[2], sv=sv, final=final,
-                        risk=jnp.asarray(best[0]), rounds=rounds_done,
-                        history=tuple(history))
+        # Final consolidated model: retrain on SV_global alone
+        # (cascade-style).
+        with jax.profiler.TraceAnnotation("mr.final"):
+            final = _final_fit_jit(sv, params, cfg=cfg)
+        return MapReduceSVM(w=best[1], b=best[2], sv=sv, final=final,
+                            risk=jnp.asarray(best[0]), rounds=rounds_done,
+                            history=tuple(history))
 
 
 def predict(model: MapReduceSVM, X: jax.Array, cfg: MRSVMConfig,
@@ -444,29 +460,30 @@ def _round_candidates(Xl, yl, ml, sv: SVBuffer, cfg: MRSVMConfig,
     p = cfg.svm.params() if params is None else params
     # map + reduce (original ``params``, not ``p`` — see mapreduce_round)
     res = _fit_union(Xl, yl, ml, sv, cfg.svm, params, vma_axes=axes)
-    home_alpha = res.alpha[:per]
-    copy_alpha = res.alpha[per:] * sv.mask
+    with jax.named_scope("mr.merge"):
+        home_alpha = res.alpha[:per]
+        copy_alpha = res.alpha[per:] * sv.mask
 
-    # union semantics: fold the max appended-copy α back into the
-    # home rows (buffer row with global id g lives on device g//per).
-    buf_alpha = compat.pmax(copy_alpha, axes)           # (cap,)
-    mine = jnp.logical_and(sv.ids >= 0, sv.ids // per == idx)
-    pos = jnp.where(mine, sv.ids % per, 0)
-    folded = jnp.zeros((per,), home_alpha.dtype).at[pos].max(
-        jnp.where(mine, buf_alpha, 0.0))
-    home_alpha = jnp.maximum(home_alpha, folded) * ml
+        # union semantics: fold the max appended-copy α back into the
+        # home rows (buffer row with global id g lives on device g//per).
+        buf_alpha = compat.pmax(copy_alpha, axes)           # (cap,)
+        mine = jnp.logical_and(sv.ids >= 0, sv.ids // per == idx)
+        pos = jnp.where(mine, sv.ids % per, 0)
+        folded = jnp.zeros((per,), home_alpha.dtype).at[pos].max(
+            jnp.where(mine, buf_alpha, 0.0))
+        home_alpha = jnp.maximum(home_alpha, folded) * ml
 
-    # balanced top-k per device — the candidate chunk of the shuffle
-    topv, topi = jax.lax.top_k(home_alpha, k)
-    live = (topv > p.sv_threshold).astype(Xl.dtype)
-    cand_ids = (idx * per + topi).astype(jnp.int32)
-    cand = SVBuffer(
-        x=Xl[topi] * live[:, None],
-        y=yl[topi] * live,
-        alpha=topv * live,
-        ids=jnp.where(live > 0, cand_ids, -1),
-        mask=live,
-    )
+        # balanced top-k per device — the candidate chunk of the shuffle
+        topv, topi = jax.lax.top_k(home_alpha, k)
+        live = (topv > p.sv_threshold).astype(Xl.dtype)
+        cand_ids = (idx * per + topi).astype(jnp.int32)
+        cand = SVBuffer(
+            x=Xl[topi] * live[:, None],
+            y=yl[topi] * live,
+            alpha=topv * live,
+            ids=jnp.where(live > 0, cand_ids, -1),
+            mask=live,
+        )
     return cand, res.w, res.b
 
 
@@ -481,25 +498,26 @@ def _device_risks(scores, yl, ml, cfg: MRSVMConfig, axes, ndev: int):
     message and the reduction finishes in log2(ndev) hops instead of
     the flat all-reduce's implementation-chosen schedule (§16).
     """
-    if cfg.risk_loss == "hinge":
-        per_ex = jnp.maximum(0.0, 1.0 - yl[:, None] * scores)
-    else:
-        # Shared decision convention (score >= 0 → +1) with
-        # risk_lib.zero_one_loss / predict — see that docstring.
-        per_ex = risk_lib.zero_one_loss(scores, yl[:, None]).astype(
-            scores.dtype)
-    part = jnp.sum(per_ex * ml[:, None], axis=0)
-    cnt = jnp.sum(ml)
-    if cfg.converge_impl == "tree":
-        vec = jnp.concatenate([part, cnt.reshape(1).astype(part.dtype)])
-        s = 1
-        while s < ndev:                  # power of two — build-time checked
-            vec = vec + compat.ppermute(
-                vec, axes, [(i, i ^ s) for i in range(ndev)])
-            s <<= 1
-        return vec[:-1] / jnp.maximum(vec[-1], 1.0)
-    return compat.psum(part, axes) / jnp.maximum(
-        compat.psum(cnt, axes), 1.0)
+    with jax.named_scope("mr.score"):
+        if cfg.risk_loss == "hinge":
+            per_ex = jnp.maximum(0.0, 1.0 - yl[:, None] * scores)
+        else:
+            # Shared decision convention (score >= 0 → +1) with
+            # risk_lib.zero_one_loss / predict — see that docstring.
+            per_ex = risk_lib.zero_one_loss(scores, yl[:, None]).astype(
+                scores.dtype)
+        part = jnp.sum(per_ex * ml[:, None], axis=0)
+        cnt = jnp.sum(ml)
+        if cfg.converge_impl == "tree":
+            vec = jnp.concatenate([part, cnt.reshape(1).astype(part.dtype)])
+            s = 1
+            while s < ndev:              # power of two — build-time checked
+                vec = vec + compat.ppermute(
+                    vec, axes, [(i, i ^ s) for i in range(ndev)])
+                s <<= 1
+            return vec[:-1] / jnp.maximum(vec[-1], 1.0)
+        return compat.psum(part, axes) / jnp.maximum(
+            compat.psum(cnt, axes), 1.0)
 
 
 def _pack_lanes(xw, wire_dt):

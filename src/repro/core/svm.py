@@ -347,13 +347,17 @@ def fit_binary(X: jax.Array, y: jax.Array, mask: Optional[jax.Array] = None,
     both): the linear path reads them in place, the Gram path needs
     the union as one matrix and concatenates.
     """
-    if cfg.kernel.name == "linear" and not cfg.use_gram:
-        return fit_binary_linear(X, y, mask, cfg, params=params,
-                                 vma_axes=vma_axes, tail=tail)
-    if tail is not None:
-        X = sparse_rows.rows_concat(X, tail, axis=0)
-    return fit_binary_kernel(X, y, mask, cfg, gram_fn=gram_fn, params=params,
-                             vma_axes=vma_axes)
+    # Every dual-CD path runs under this scope: the device ops of the
+    # local and final solves carry it in their ``op_name`` metadata
+    # (DESIGN.md §17).
+    with jax.named_scope("svm.solve"):
+        if cfg.kernel.name == "linear" and not cfg.use_gram:
+            return fit_binary_linear(X, y, mask, cfg, params=params,
+                                     vma_axes=vma_axes, tail=tail)
+        if tail is not None:
+            X = sparse_rows.rows_concat(X, tail, axis=0)
+        return fit_binary_kernel(X, y, mask, cfg, gram_fn=gram_fn,
+                                 params=params, vma_axes=vma_axes)
 
 
 # ---------------------------------------------------------------------------

@@ -180,44 +180,46 @@ def _run_rounds(step, svb, d: int, cfg: MRSVMConfig,
     frozen = None if snapshot is not None else svb
     inf = jnp.asarray(np.inf, params.tol.dtype)
     for t in range(cfg.max_rounds):
-        guard = (no_retrace(f"[{tag}] steady-state round {t}")
-                 if fail_on_retrace and t >= 1
-                 else contextlib.nullcontext())
-        with guard:
-            dmask = jnp.asarray(done)
-            eff = params._replace(
-                tol=jnp.where(dmask, inf, params.tol),
-                max_epochs=jnp.where(dmask, 0.0, params.max_epochs))
-            sv_new, r_star, ws, bs = step(svb, eff)
-            if snapshot is None:
-                frozen = _freeze(done, frozen, sv_new)
-            svb = frozen if snapshot is None else sv_new
-            with allowed_host_sync("eq. 8 convergence readback"):
-                r_star = np.asarray(r_star)
-        act = ~done
-        faults.check_finite_risks(r_star, where=f"{tag} round {t}",
-                                  mask=act)
-        improved = act & (r_star < best_risk)
-        if improved.any():
-            with allowed_host_sync("improved-hypothesis readback"):
-                best_w[improved] = np.asarray(ws)[improved]
-                best_b[improved] = np.asarray(bs)[improved]
-            best_risk = np.where(improved, r_star, best_risk)
-        rounds[act] += 1
-        history.append({"round": t, "risks": np.where(act, r_star, np.nan),
-                        "active": int(act.sum())})
-        if verbose:
-            print(f"[{tag}] round={t} active={int(act.sum())}/{S} "
-                  f"best_R_emp={np.nanmin(np.where(act, r_star, np.nan)):.5f}")
-        newly = act & (t > 0) & (np.abs(prev - r_star) <= cfg.gamma)  # eq. 8
-        if snapshot is not None and (newly.any()
-                                     or t == cfg.max_rounds - 1):
-            exp = snapshot(sv_new)
-            frozen = exp if frozen is None else _freeze(done, frozen, exp)
-        done |= newly
-        prev = np.where(act, r_star, prev)
-        if done.all():
-            break
+        with jax.profiler.TraceAnnotation("mr.round", round=t):
+            guard = (no_retrace(f"[{tag}] steady-state round {t}")
+                     if fail_on_retrace and t >= 1
+                     else contextlib.nullcontext())
+            with guard:
+                dmask = jnp.asarray(done)
+                eff = params._replace(
+                    tol=jnp.where(dmask, inf, params.tol),
+                    max_epochs=jnp.where(dmask, 0.0, params.max_epochs))
+                sv_new, r_star, ws, bs = step(svb, eff)
+                if snapshot is None:
+                    frozen = _freeze(done, frozen, sv_new)
+                svb = frozen if snapshot is None else sv_new
+                with allowed_host_sync("eq. 8 convergence readback"), \
+                        jax.profiler.TraceAnnotation("mr.eq8", round=t):
+                    r_star = np.asarray(r_star)
+            act = ~done
+            faults.check_finite_risks(r_star, where=f"{tag} round {t}",
+                                      mask=act)
+            improved = act & (r_star < best_risk)
+            if improved.any():
+                with allowed_host_sync("improved-hypothesis readback"):
+                    best_w[improved] = np.asarray(ws)[improved]
+                    best_b[improved] = np.asarray(bs)[improved]
+                best_risk = np.where(improved, r_star, best_risk)
+            rounds[act] += 1
+            history.append({"round": t, "risks": np.where(act, r_star, np.nan),
+                            "active": int(act.sum())})
+            if verbose:
+                print(f"[{tag}] round={t} active={int(act.sum())}/{S} "
+                      f"best_R_emp={np.nanmin(np.where(act, r_star, np.nan)):.5f}")
+            newly = act & (t > 0) & (np.abs(prev - r_star) <= cfg.gamma)  # eq. 8
+            if snapshot is not None and (newly.any()
+                                         or t == cfg.max_rounds - 1):
+                exp = snapshot(sv_new)
+                frozen = exp if frozen is None else _freeze(done, frozen, exp)
+            done |= newly
+            prev = np.where(act, r_star, prev)
+            if done.all():
+                break
     return frozen, best_risk, best_w, best_b, rounds, tuple(history)
 
 
@@ -279,45 +281,47 @@ def fit_mapreduce_sweep(X: jax.Array, y: jax.Array, num_partitions: int,
     identical to a sequential ``fit_mapreduce`` call with its
     ``params``/data slice.
     """
-    S = _num_configs(params)
-    n, d = X.shape[-2], X.shape[-1]
-    L = num_partitions
-    per = -(-n // L)
-    pad = L * per - n
-    if X.ndim == 3 and X.shape[0] != S:
-        raise ValueError(f"per-job X has leading axis {X.shape[0]}, "
-                         f"expected S={S}")
-    x_ax = 0 if X.ndim == 3 else None
-    yb = jnp.broadcast_to(jnp.atleast_2d(y.astype(X.dtype)), (S, n))
-    ypb = jnp.pad(yb, ((0, 0), (0, pad))).reshape(S, L, per)
-    base_mask = (jnp.ones((n,), X.dtype) if mask is None
-                 else mask.astype(X.dtype))
-    if base_mask.ndim == 2:
-        maskp = jnp.pad(base_mask, ((0, 0), (0, pad))).reshape(S, L, per)
-        m_ax = 0
-    else:
-        maskp = jnp.pad(base_mask, (0, pad)).reshape(L, per)
-        m_ax = None
+    with jax.profiler.TraceAnnotation("mr.fit"):
+        S = _num_configs(params)
+        n, d = X.shape[-2], X.shape[-1]
+        L = num_partitions
+        per = -(-n // L)
+        pad = L * per - n
+        if X.ndim == 3 and X.shape[0] != S:
+            raise ValueError(f"per-job X has leading axis {X.shape[0]}, "
+                             f"expected S={S}")
+        x_ax = 0 if X.ndim == 3 else None
+        yb = jnp.broadcast_to(jnp.atleast_2d(y.astype(X.dtype)), (S, n))
+        ypb = jnp.pad(yb, ((0, 0), (0, pad))).reshape(S, L, per)
+        base_mask = (jnp.ones((n,), X.dtype) if mask is None
+                     else mask.astype(X.dtype))
+        if base_mask.ndim == 2:
+            maskp = jnp.pad(base_mask, ((0, 0), (0, pad))).reshape(S, L, per)
+            m_ax = 0
+        else:
+            maskp = jnp.pad(base_mask, (0, pad)).reshape(L, per)
+            m_ax = None
 
-    sv0 = init_sv_buffer(
-        cfg.sv_capacity, d, X.dtype,
-        nnz_cap=X.nnz_cap if sparse_rows.is_sparse(X) else None)
-    svb = compat.tree_map(
-        lambda a: jnp.broadcast_to(a, (S,) + a.shape), sv0)
+        sv0 = init_sv_buffer(
+            cfg.sv_capacity, d, X.dtype,
+            nnz_cap=X.nnz_cap if sparse_rows.is_sparse(X) else None)
+        svb = compat.tree_map(
+            lambda a: jnp.broadcast_to(a, (S,) + a.shape), sv0)
 
-    def step(sv_b, eff):
-        return _sweep_round_jit(X, ypb, maskp, sv_b, eff,
-                                cfg=cfg, x_ax=x_ax, m_ax=m_ax, L=L)
+        def step(sv_b, eff):
+            return _sweep_round_jit(X, ypb, maskp, sv_b, eff,
+                                    cfg=cfg, x_ax=x_ax, m_ax=m_ax, L=L)
 
-    svb, best_risk, best_w, best_b, rounds, history = _run_rounds(
-        step, svb, d, cfg, params, verbose, "sweep",
-        fail_on_retrace=fail_on_retrace)
+        svb, best_risk, best_w, best_b, rounds, history = _run_rounds(
+            step, svb, d, cfg, params, verbose, "sweep",
+            fail_on_retrace=fail_on_retrace)
 
-    # Final consolidated models: retrain each config on its SV_global.
-    final = _sweep_final_jit(svb, params, cfg=cfg)
-    return SweepResult(params=params, risks=jnp.asarray(best_risk),
-                       ws=jnp.asarray(best_w), bs=jnp.asarray(best_b),
-                       sv=svb, final=final, rounds=rounds, history=history)
+        # Final consolidated models: retrain each config on its SV_global.
+        with jax.profiler.TraceAnnotation("mr.final"):
+            final = _sweep_final_jit(svb, params, cfg=cfg)
+        return SweepResult(params=params, risks=jnp.asarray(best_risk),
+                           ws=jnp.asarray(best_w), bs=jnp.asarray(best_b),
+                           sv=svb, final=final, rounds=rounds, history=history)
 
 
 def sweep_decision_values(res: SweepResult, X: jax.Array,

@@ -76,14 +76,18 @@ from repro.core.sweep import fit_mapreduce_sweep, stack_params
 _MANIFEST = "service_manifest.json"
 
 
-def _all_finite(X, y) -> bool:
+def _all_finite(X, y, uid: int) -> bool:
     """Whether a micro-batch's features and labels are all finite —
     the quarantine gate at the submit() boundary (DESIGN.md §15): one
     NaN row folded into SV_global poisons the model for every later
-    reader, so the check runs once per batch, not per fold."""
+    reader, so the check runs once per batch, not per fold. ``uid`` is
+    the batch's, for the ``svc.quarantine`` span (DESIGN.md §17)."""
     vals = X.values if sparse_rows.is_sparse(X) else X
-    return bool(np.isfinite(np.asarray(vals)).all()
-                and np.isfinite(np.asarray(y)).all())
+    with jax.profiler.TraceAnnotation(
+            "svc.quarantine", uid=uid, rows=int(X.shape[0]),
+            bytes=int(vals.nbytes + y.nbytes)):
+        return bool(np.isfinite(np.asarray(vals)).all()
+                    and np.isfinite(np.asarray(y)).all())
 
 
 @functools.partial(jax.jit, static_argnames=("n_max", "width"))
@@ -579,7 +583,9 @@ class StreamingSVMService:
                 f"{self.cluster.process_index} of "
                 f"{self.cluster.process_count} (snapshots stay readable "
                 "here — route submissions to the coordinator)")
-        with self._cv:
+        batches = list(batches)
+        with jax.profiler.TraceAnnotation("svc.submit",
+                                          batches=len(batches)), self._cv:
             uids = [self._enqueue(stream, X, y) for stream, X, y in batches]
             self._cv.notify_all()
         return uids
@@ -619,7 +625,7 @@ class StreamingSVMService:
                 f"stream {stream!r} serves nnz_cap={sv_x.nnz_cap} "
                 f"rows but the batch has nnz_cap={X.nnz_cap} — "
                 "re-block with the model's cap")
-        if self.quarantine and not _all_finite(X, y):
+        if self.quarantine and not _all_finite(X, y, self._uid + 1):
             # NaN/Inf never reaches a fold: one poisoned row in
             # SV_global would corrupt every later wave's model.
             # The batch is acknowledged (uid) but diverted —
@@ -699,14 +705,19 @@ class StreamingSVMService:
     def _swap(self, stream: str, model: MapReduceSVM,
               params: Optional[SolverParams]) -> ModelSnapshot:
         """Atomically publish a fully-materialized new snapshot."""
-        jax.block_until_ready((model.sv, model.final, model.w, model.b))
-        with self._lock:
-            old = self._snapshots[stream]
-            snap = ModelSnapshot(model=model, params=params,
-                                 version=old.version + 1)
-            self._snapshots[stream] = snap
-            if self.keep_history:
-                self._history[stream][snap.version] = snap
+        # folds are serialised by ``_wave_lock``: this is the version
+        # the swap publishes
+        with jax.profiler.TraceAnnotation(
+                "svc.swap", stream=stream,
+                version=self._snapshots[stream].version + 1):
+            jax.block_until_ready((model.sv, model.final, model.w, model.b))
+            with self._lock:
+                old = self._snapshots[stream]
+                snap = ModelSnapshot(model=model, params=params,
+                                     version=old.version + 1)
+                self._snapshots[stream] = snap
+                if self.keep_history:
+                    self._history[stream][snap.version] = snap
         return snap
 
     def run_wave(self) -> Optional[StreamWaveStats]:
@@ -716,9 +727,16 @@ class StreamingSVMService:
         there (see :meth:`submit`)."""
         if not self._admits:
             return None
-        with self._wave_lock:
+        with self._wave_lock, jax.profiler.TraceAnnotation(
+                "svc.wave", wave=self._wave):
             t0 = time.time()
-            admitted = self._admit()
+            with jax.profiler.TraceAnnotation("svc.admit",
+                                              wave=self._wave) as span:
+                admitted = self._admit()
+                # a comma would split the span's ``name#k=v,k=v#`` form
+                span.set_metadata(uids=" ".join(
+                    str(mb.uid) for _, take in admitted.values()
+                    for mb in take))
             if not admitted:
                 return None
             wave_id = self._wave
@@ -904,33 +922,35 @@ class StreamingSVMService:
         n_max = max(int(joined[s][2].shape[0]) for s in names) + cap
 
         width = self._bucket_width(len(names))
-        Xb = _stack_jobs(tuple(joined[s][2] for s in names),
-                         tuple(joined[s][0].model.sv.x for s in names),
-                         n_max=n_max, width=width)   # (S', n_max, d)
-        ys, ms, ps = [], [], []
-        for s in names:
-            snap, _, Xn, yn = joined[s]
-            sv = snap.model.sv
-            n_new = int(Xn.shape[0])
-            pad = n_max - n_new - cap
-            dt = yn.dtype
-            ys.append(jnp.concatenate(
-                [yn, sv.y.astype(dt), jnp.zeros((pad,), dt)], axis=0))
-            ms.append(jnp.concatenate(
-                [jnp.ones((n_new,), dt), sv.mask.astype(dt),
-                 jnp.zeros((pad,), dt)], axis=0))
-            ps.append(snap.params if snap.params is not None
-                      else self.cfg.svm.params())
-        # Elastic job axis: pad to the bucket width with all-masked
-        # zero jobs (their results are discarded below) so a wave of
-        # any tenant count reuses the bucket's compiled program.
-        for _ in range(width - len(names)):
-            ys.append(jnp.zeros_like(ys[0]))
-            ms.append(jnp.zeros_like(ms[0]))
-            ps.append(ps[0])
-        yb = jnp.stack(ys)                       # (S', n_max)
-        mb_ = jnp.stack(ms)                      # (S', n_max)
-        params_b = stack_params(ps)
+        with jax.profiler.TraceAnnotation("svc.stack", width=width,
+                                          rows=n_max):
+            Xb = _stack_jobs(tuple(joined[s][2] for s in names),
+                             tuple(joined[s][0].model.sv.x for s in names),
+                             n_max=n_max, width=width)   # (S', n_max, d)
+            ys, ms, ps = [], [], []
+            for s in names:
+                snap, _, Xn, yn = joined[s]
+                sv = snap.model.sv
+                n_new = int(Xn.shape[0])
+                pad = n_max - n_new - cap
+                dt = yn.dtype
+                ys.append(jnp.concatenate(
+                    [yn, sv.y.astype(dt), jnp.zeros((pad,), dt)], axis=0))
+                ms.append(jnp.concatenate(
+                    [jnp.ones((n_new,), dt), sv.mask.astype(dt),
+                     jnp.zeros((pad,), dt)], axis=0))
+                ps.append(snap.params if snap.params is not None
+                          else self.cfg.svm.params())
+            # Elastic job axis: pad to the bucket width with all-masked
+            # zero jobs (their results are discarded below) so a wave of
+            # any tenant count reuses the bucket's compiled program.
+            for _ in range(width - len(names)):
+                ys.append(jnp.zeros_like(ys[0]))
+                ms.append(jnp.zeros_like(ms[0]))
+                ps.append(ps[0])
+            yb = jnp.stack(ys)                       # (S', n_max)
+            mb_ = jnp.stack(ms)                      # (S', n_max)
+            params_b = stack_params(ps)
 
         sig = self._fold_signature("batched", Xb, yb, mb_, params_b)
         with self._retrace_guard(
@@ -1070,16 +1090,24 @@ class StreamingSVMService:
     # -- reporting ---------------------------------------------------------
 
     def throughput_report(self) -> Dict[str, float]:
+        """The service's counts and rates so far. ``wall_s`` is the
+        summed wall time of the folds (waves) alone; ``rows_per_s``
+        divides the rows folded by the time from the first completed
+        batch's submission to the last one's completion, so the time
+        between waves (submits, queueing, idle) counts against it."""
         lats = [mb.latency_s for mb in self.done]
         queues = [mb.queue_s for mb in self.done]
         rows = sum(s.rows for s in self.stats)
         wall = sum(s.wall_s for s in self.stats)
+        span = (max(mb.completed_s for mb in self.done)
+                - min(mb.submitted_s for mb in self.done)
+                if self.done else 0.0)
         return {
             "batches": len(self.done),
             "rows": rows,
             "waves": len(self.stats),
             "wall_s": round(wall, 3),
-            "rows_per_s": round(rows / max(wall, 1e-9), 1),
+            "rows_per_s": round(rows / max(span, 1e-9), 1),
             "mean_latency_s": round(float(np.mean(lats)), 4) if lats else 0.0,
             "p95_latency_s": (round(float(np.percentile(lats, 95)), 4)
                               if lats else 0.0),

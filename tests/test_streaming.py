@@ -123,6 +123,28 @@ def test_batched_wave_matches_per_stream_updates(stream_cfg):
             rtol=1e-4, atol=1e-4)
 
 
+def test_throughput_counts_the_time_between_waves(stream_cfg):
+    """``rows_per_s`` is rows over first submission → last completion,
+    not over the summed fold walls (``wall_s``), which leave out the
+    time between waves."""
+    import time
+    cfg = stream_cfg
+    svc = StreamingSVMService(cfg, num_partitions=4)
+    X0, y0 = _sep_data(5, 128)
+    svc.register("a", fit_mapreduce(X0, y0, 4, cfg))
+    for k in range(2):
+        svc.submit("a", *_sep_data(30 + k, 64))
+        svc.run_wave()
+        time.sleep(0.3)
+    rep = svc.throughput_report()
+    span = (max(mb.completed_s for mb in svc.done)
+            - min(mb.submitted_s for mb in svc.done))
+    assert rep["rows"] == 128 and rep["waves"] == 2
+    assert rep["wall_s"] == round(sum(st.wall_s for st in svc.stats), 3)
+    assert rep["rows_per_s"] == round(128 / span, 1)
+    assert span >= sum(st.wall_s for st in svc.stats) + 0.3
+
+
 def test_sweep_per_job_data_matches_sequential(stream_cfg):
     """The substrate itself: fit_mapreduce_sweep with per-job (X, y,
     mask) must equal per-job fit_mapreduce runs."""
