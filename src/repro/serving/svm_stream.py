@@ -76,18 +76,26 @@ from repro.core.sweep import fit_mapreduce_sweep, stack_params
 _MANIFEST = "service_manifest.json"
 
 
+@jax.jit
+def _finite_flag(vals, y):
+    """A 0-d bool on the device: every feature value and label of a
+    micro-batch is finite. One reduction; the rows stay where they are."""
+    return jnp.isfinite(vals).all() & jnp.isfinite(y).all()
+
+
 def _all_finite(X, y, uid: int) -> bool:
     """Whether a micro-batch's features and labels are all finite —
     the quarantine gate at the submit() boundary (DESIGN.md §15): one
     NaN row folded into SV_global poisons the model for every later
-    reader, so the check runs once per batch, not per fold. ``uid`` is
-    the batch's, for the ``svc.quarantine`` span (DESIGN.md §17)."""
+    reader, so the check runs once per batch, not per fold. The test
+    runs on the device and only its flag is read back: the host never
+    copies the rows. ``uid`` is the batch's, for the ``svc.quarantine``
+    span (DESIGN.md §17)."""
     vals = X.values if sparse_rows.is_sparse(X) else X
     with jax.profiler.TraceAnnotation(
             "svc.quarantine", uid=uid, rows=int(X.shape[0]),
             bytes=int(vals.nbytes + y.nbytes)):
-        return bool(np.isfinite(np.asarray(vals)).all()
-                    and np.isfinite(np.asarray(y)).all())
+        return bool(jax.device_get(_finite_flag(vals, y)))
 
 
 @functools.partial(jax.jit, static_argnames=("n_max", "width"))

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import faults
+from repro import faults, sparse
 from repro.ckpt import checkpoint as ckpt
 from repro.core import MRSVMConfig, SVMConfig, fit_mapreduce
 from repro.serving import StreamingSVMService
@@ -341,6 +341,79 @@ def test_quarantine_diverts_nonfinite_batches(svc_cfg):
     svc2.register("t", fit_mapreduce(X0, y0, 4, svc_cfg))
     svc2.submit("t", jnp.asarray(Xp), _sep_data(1, 64)[1])
     assert svc2.pending() == 1
+
+
+@pytest.fixture(scope="module")
+def gate_models(svc_cfg):
+    """One fitted dense and one blocked-CSR stream model, shared by the
+    gate's cases (registering a model does not change it)."""
+    sp_cfg = MRSVMConfig(sv_capacity=64, gamma=1e-4, max_rounds=3,
+                         svm=SVMConfig(C=1.0, max_epochs=15,
+                                       row_format="sparse_csr", nnz_cap=8))
+    X0, y0 = _sep_data(0, 128)
+    Xs0 = sparse.from_dense(X0 * (jnp.abs(X0) > 1.0), 8)
+    return {"dense": (svc_cfg, fit_mapreduce(X0, y0, 4, svc_cfg)),
+            "csr": (sp_cfg, fit_mapreduce(Xs0, y0, 4, sp_cfg))}
+
+
+def _gate_batch(fmt, poison):
+    X, y = (np.array(a) for a in _sep_data(1, 64))
+    bad = {"nan_x": (X, (3, 2), np.nan), "posinf_x": (X, (5, 0), np.inf),
+           "neginf_y": (y, (7,), -np.inf)}.get(poison)
+    if bad:
+        a, idx, value = bad
+        a[idx] = value
+    if fmt == "dense":
+        return jnp.asarray(X), jnp.asarray(y)
+    Xs = sparse.from_dense(jnp.asarray(X * (np.abs(X) > 1.0)), 8)
+    if poison == "nan_values":
+        Xs = sparse.SparseRows(Xs.indices, Xs.values.at[3, 0].set(jnp.nan),
+                               Xs.d)
+    return Xs, jnp.asarray(y)
+
+
+@pytest.mark.parametrize("fmt,poison", [
+    ("dense", "nan_x"), ("dense", "posinf_x"), ("dense", "neginf_y"),
+    ("csr", "nan_values"), ("dense", None), ("csr", None)])
+def test_quarantine_gate_by_format_and_value(gate_models, fmt, poison):
+    cfg, model = gate_models[fmt]
+    svc = StreamingSVMService(cfg, num_partitions=4)
+    svc.register("t", model)
+    before = faults.counters().get("quarantined", 0)
+    uid = svc.submit("t", *_gate_batch(fmt, poison))
+    assert uid > 0                           # acknowledged either way
+    diverted = poison is not None
+    assert len(svc.quarantined) == int(diverted)
+    assert svc.pending() == int(not diverted)
+    assert faults.counters().get("quarantined", 0) - before == int(diverted)
+    assert svc.throughput_report()["quarantined"] == int(diverted)
+
+
+def test_quarantine_diverts_only_the_poisoned_batch_of_a_submit(gate_models):
+    cfg, model = gate_models["dense"]
+    svc = StreamingSVMService(cfg, num_partitions=4)
+    svc.register("t", model)
+    clean = _gate_batch("dense", None)
+    uids = svc.submit_many([("t",) + clean,
+                            ("t",) + _gate_batch("dense", "nan_x")])
+    assert len(set(uids)) == 2
+    assert [mb.uid for mb in svc.quarantined] == [uids[1]]
+    assert svc.pending() == 1
+    assert svc.run_wave() is not None        # the clean batch folds
+    assert [mb.uid for mb in svc.done] == [uids[0]]
+    assert svc.pending() == 0 and svc.snapshot("t").version == 1
+    assert svc.throughput_report()["quarantined"] == 1
+
+
+def test_quarantine_gate_reads_back_one_scalar():
+    """The gate's device program returns a 0-d bool, so the host reads
+    one flag a batch and never the rows."""
+    from repro.serving.svm_stream import _finite_flag
+    X, y = _gate_batch("dense", None)
+    Xs, ys = _gate_batch("csr", None)
+    for vals, labels in ((X, y), (Xs.values, ys)):
+        (aval,) = jax.make_jaxpr(_finite_flag)(vals, labels).out_avals
+        assert aval.shape == () and aval.dtype == jnp.bool_
 
 
 def test_injected_poison_rows_are_quarantined(svc_cfg):
