@@ -59,8 +59,11 @@ class Chain:
 
 
 def job_rows(rows_model, tenant: int, chain: Chain, v: int, acc):
-    """The reference's ``(rows, y, mask, n)`` of version ``v``'s job."""
+    """The reference's ``(rows, y, mask, n)`` of version ``v``'s job, in
+    the row model's format (a dense array, or a CSR pair whose arrays are
+    taken row by row alike)."""
     import jax.numpy as jnp
+    from jax.tree_util import tree_map
     from bench import reference as ref
 
     src = chain.sources(v)
@@ -74,16 +77,17 @@ def job_rows(rows_model, tenant: int, chain: Chain, v: int, acc):
         rows = chain.archive_rows if k < 0 else chain.batch_rows
         X, y = rows_model.make(tenant, k, rows)
         r = jnp.asarray([src[p][1] for p in positions], jnp.int32)
-        pieces.append(jnp.take(X, r, axis=0))
+        pieces.append(tree_map(lambda a: jnp.take(a, r, axis=0), X))
         ys.append(jnp.take(y, r).astype(jnp.float32))
         order += positions
     empty = [p for p, s in enumerate(src) if s is None]
     if empty:
-        pieces.append(jnp.zeros((len(empty),) + pieces[0].shape[1:],
-                                pieces[0].dtype))
+        pieces.append(tree_map(lambda a: jnp.zeros(
+            (len(empty),) + a.shape[1:], a.dtype), pieces[0]))
         ys.append(jnp.zeros((len(empty),), jnp.float32))
         order += empty
     perm = jnp.asarray(np.argsort(np.asarray(order)), jnp.int32)
-    X = jnp.take(jnp.concatenate(pieces), perm, axis=0)
+    X = tree_map(lambda *a: jnp.take(jnp.concatenate(a), perm, axis=0),
+                *pieces)
     y = jnp.concatenate(ys)[perm]
     return ref.rows_from(X, acc), y, jnp.asarray(chain.mask(v)), n

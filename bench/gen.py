@@ -11,8 +11,13 @@ labels ``sign(x·w + 1e-3)``) and the serve launcher's per-tenant drift
 
 Columns are drawn one per stratum of width ``d // nnz_max``, so the
 indices of a row are distinct, and ``nnz`` of a row is uniform in
-``[nnz_min, nnz_max]``. Rows are dense ``(rows, d)`` arrays, the
-program's dense row type.
+``[nnz_min, nnz_max]``. Rows come in the configuration's
+``row_format``: ``dense`` rows are ``(rows, d)`` arrays; ``sparse_csr``
+rows are the pair ``(cols, vals)`` of ``(rows, nnz_cap)`` column ids
+(int32) and values, ``nnz_cap`` the largest ``row_nnz``, with a row's
+dead slots ``(0, 0.0)``: the program's blocked-CSR layout, which the
+drivers wrap as its row type. Both come from the same draws, so a CSR
+batch scattered into a dense one is the dense batch, bit for bit.
 """
 from __future__ import annotations
 
@@ -42,9 +47,9 @@ def stream_key(seed: int, tenant: int, batch: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("rows", "d", "nnz_min",
                                              "nnz_max", "signal_dims",
-                                             "dtype"))
+                                             "dtype", "csr"))
 def _batch(key, tkey, step, drift, *, rows, d, nnz_min, nnz_max,
-           signal_dims, dtype):
+           signal_dims, dtype, csr=False):
     kc, kv, kn = jax.random.split(key, 3)
     stride = d // nnz_max
     offs = jax.random.randint(kc, (rows, nnz_max), 0, stride)
@@ -64,20 +69,29 @@ def _batch(key, tkey, step, drift, *, rows, d, nnz_min, nnz_max,
         + drift * step * jax.random.normal(kd, (signal_dims,))))
     score = jnp.sum(jnp.take(w, cols) * vals.astype(jnp.float32), axis=1)
     y = jnp.where(score + 1e-3 >= 0, 1.0, -1.0).astype(dtype)
+    if csr:
+        return (cols, vals), y
     r = jnp.broadcast_to(jnp.arange(rows)[:, None], cols.shape)
     X = jnp.zeros((rows, d), dtype).at[r, cols].add(vals)
     return X, y
 
 
+def nnz_cap(cfg: dict) -> int:
+    """Slots of a blocked-CSR row: the largest ``row_nnz``."""
+    return int(cfg["row_nnz"][1])
+
+
 class RowModel:
     """The rows of one configuration. ``make(tenant, batch, rows)``
-    returns ``(X, y)`` on the device, ``X`` dense ``(rows, d)``."""
+    returns ``(X, y)`` on the device: ``X`` dense ``(rows, d)``, or
+    ``(cols, vals)`` where ``csr``."""
 
     def __init__(self, cfg: dict, seed: int):
         self.seed = seed
         self.d = int(cfg["num_features"])
-        if cfg["row_format"] != "dense":
-            raise ValueError("only dense rows are made")
+        if cfg["row_format"] not in ("dense", "sparse_csr"):
+            raise ValueError(f"no rows of format {cfg['row_format']!r}")
+        self.csr = cfg["row_format"] == "sparse_csr"
         self.dtype = jnp.dtype(cfg["dtype"])
         self.nnz_min, self.nnz_max = (int(v) for v in cfg["row_nnz"])
         self.signal_dims = int(cfg["signal_dims"])
@@ -92,5 +106,6 @@ class RowModel:
                       jnp.float32(step), jnp.float32(self.drift),
                       rows=rows, d=self.d, nnz_min=self.nnz_min,
                       nnz_max=self.nnz_max, signal_dims=self.signal_dims,
-                      dtype=self.dtype if dtype is None else dtype)
+                      dtype=self.dtype if dtype is None else dtype,
+                      csr=self.csr)
         return X, y

@@ -19,11 +19,19 @@ job to ``L·per`` rows, partitions it (row ``g`` lives on partition
   when two rounds' risks differ by at most γ (eq. 8);
 * the consolidated model is one more solve on the SV buffer alone.
 
-Rows are held once, as a dense ``(n + 1, d)`` array with a zero row at
-the end (an empty SV slot reads it). A partition's solve walks its home
-rows in place and then one shared copy of the SV buffer's rows, so no
-union is copied. ``acc`` is the precision of the solver's state and of
-its products: float32 for the reference, bfloat16 for its control.
+Rows are held once, with a zero row at the end (an empty SV slot reads
+it): a dense ``(n + 1, d)`` array, or blocked-CSR rows (``Csr``: column
+ids and values, ``(n + 1, nnz_cap)`` each, a dead slot ``(0, 0.0)``).
+A CSR row step gathers ``w`` at the row's columns for ``w·x`` and
+scatter-adds its update there, ``Q_ii`` sums the row's squared values,
+and the eq. 6 scores gather each hypothesis at the columns, one
+hypothesis at a time; so no array of the CSR path has a row axis and the
+feature axis together, and a job of any width needs beside its rows only
+the ``L × d`` hypotheses. A partition's solve walks its home rows in
+place and then one shared copy of the SV buffer's rows, so no union is
+copied. ``acc`` is the precision of the solver's state and of its
+products (and of the rows' values): float32 for the reference, bfloat16
+for its control.
 """
 from __future__ import annotations
 
@@ -37,40 +45,80 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+class Csr(NamedTuple):
+    """Blocked-CSR rows: ``cols`` (int32) and ``vals``, ``(..., n,
+    nnz_cap)`` each; the columns of a row's live slots are distinct."""
+    cols: jax.Array
+    vals: jax.Array
+
+
 def rows_from(X, dtype):
-    """Job rows ``(n, d)`` with the zero row appended, in ``dtype``."""
+    """Job rows with the zero row appended, values in ``dtype``: a dense
+    ``(n, d)`` array, or a ``Csr`` from a pair ``(cols, vals)``."""
+    if isinstance(X, tuple):
+        cols, vals = (jnp.asarray(a) for a in X)
+        return Csr(jnp.concatenate([cols.astype(jnp.int32),
+                                    jnp.zeros((1, cols.shape[1]),
+                                              jnp.int32)]),
+                   rows_from(vals, dtype))
     X = jnp.asarray(X).astype(dtype)
     return jnp.concatenate([X, jnp.zeros((1, X.shape[1]), dtype)])
 
 
+def _rows(rows, f):
+    """``f`` applied to the row arrays: the dense array, or both of a
+    ``Csr``'s."""
+    return jax.tree_util.tree_map(f, rows)
+
+
+def _count(rows) -> int:
+    return jax.tree_util.tree_leaves(rows)[0].shape[0]
+
+
+def _sq_norms(B, acc):
+    """``Q_ii`` less the bias' 1: the squared values of each row."""
+    v = B.vals if isinstance(B, Csr) else B
+    return jnp.sum(jnp.square(v.astype(acc)), axis=1)
+
+
 def solve(blocks, y, m, d: int, C, tol, max_epochs: int, acc):
     """Dual CD over the rows of ``blocks`` (a tuple of ``(n_j, d)``
-    arrays), walked in order, block after block. Returns
-    ``(alpha, w, b, epochs)``."""
-    sizes = [B.shape[0] for B in blocks]
+    arrays or of ``Csr`` rows), walked in order, block after block.
+    Returns ``(alpha, w, b, epochs)``."""
+    sizes = [_count(B) for B in blocks]
     offs = [sum(sizes[:j]) for j in range(len(blocks))]
     n = sum(sizes)
     y = y.astype(acc)
     m = m.astype(acc)
-    q = jnp.concatenate([jnp.sum(jnp.square(B.astype(acc)), axis=1)
-                         for B in blocks])
+    q = jnp.concatenate([_sq_norms(B, acc) for B in blocks])
     q = jnp.where(m > 0, q + 1.0, 1.0)
     C = jnp.asarray(C, acc)
 
     def walk(B, off):
+        csr = isinstance(B, Csr)
+
         def step(r, s):
             alpha, w, b, viol = s
             i = r + off
-            x = jax.lax.dynamic_index_in_dim(B, r, keepdims=False)
-            x = x.astype(acc)
-            wx = jnp.sum(w * x)
+            if csr:
+                c = jax.lax.dynamic_index_in_dim(B.cols, r, keepdims=False)
+                x = jax.lax.dynamic_index_in_dim(B.vals, r, keepdims=False)
+                x = x.astype(acc)
+                wx = jnp.sum(w[c] * x)
+            else:
+                x = jax.lax.dynamic_index_in_dim(B, r, keepdims=False)
+                x = x.astype(acc)
+                wx = jnp.sum(w * x)
             g = y[i] * (wx + b) - 1.0
             a = alpha[i]
             pg = jnp.where(a <= 0, jnp.minimum(g, 0.0),
                            jnp.where(a >= C, jnp.maximum(g, 0.0), g))
             delta = (jnp.clip(a - g / q[i], 0.0, C) - a) * m[i]
             alpha = alpha.at[i].set(a + delta)
-            w = w + (delta * y[i]) * x
+            if csr:
+                w = w.at[c].add((delta * y[i]) * x)
+            else:
+                w = w + (delta * y[i]) * x
             b = b + delta * y[i]
             viol = jnp.maximum(viol, jnp.abs(pg) * m[i])
             return alpha, w, b, viol
@@ -97,6 +145,10 @@ def solve(blocks, y, m, d: int, C, tol, max_epochs: int, acc):
 def scores(rows, n: int, W, B):
     """``(n, k)`` decision values of the first ``n`` rows under the
     columns of ``W`` ``(d, k)``."""
+    if isinstance(rows, Csr):
+        cols, vals = rows.cols[:n], rows.vals[:n].astype(W.dtype)
+        s = jax.lax.map(lambda wk: jnp.sum(wk[cols] * vals, axis=1), W.T)
+        return s.T + B[None, :]
     X = rows[:n].astype(W.dtype)
     return jnp.matmul(X, W, precision=HIGHEST) + B[None, :]
 
@@ -127,11 +179,12 @@ def fit_round(rows, yp, mp, sv_ids, sv_mask, params, *, L, per, cap,
     """One MapReduce round. ``yp``/``mp`` are ``(L, per)``; the SV buffer
     is ``cap`` job row numbers (-1: empty slot) and its mask."""
     C, tol, thr = params
-    null = rows.shape[0] - 1
-    tail = rows[jnp.where(sv_ids >= 0, sv_ids, null)]
+    null = _count(rows) - 1
+    tail = _rows(rows, lambda a: a[jnp.where(sv_ids >= 0, sv_ids, null)])
     flat_y = yp.reshape(-1)
     sv_y = jnp.where(sv_ids >= 0, flat_y[jnp.maximum(sv_ids, 0)], 0.0)
-    home = rows[:L * per].reshape(L, per, d)
+    home = _rows(rows, lambda a: a[:L * per].reshape((L, per)
+                                                    + a.shape[1:]))
 
     def part(home_l, y_home, m_home):
         return solve((home_l, tail), jnp.concatenate([y_home, sv_y]),
@@ -162,7 +215,8 @@ def fit_round(rows, yp, mp, sv_ids, sv_mask, params, *, L, per, cap,
 def fit_final(rows, flat_y, sv_ids, params, *, d, max_epochs, acc):
     """The consolidated solve on the SV buffer's rows alone."""
     C, tol, _ = params
-    block = rows[jnp.where(sv_ids >= 0, sv_ids, rows.shape[0] - 1)]
+    block = _rows(rows, lambda a: a[jnp.where(sv_ids >= 0, sv_ids,
+                                              _count(rows) - 1)])
     y = jnp.where(sv_ids >= 0, flat_y[jnp.maximum(sv_ids, 0)], 0.0)
     m = (sv_ids >= 0).astype(acc)
     alpha, w, b, t = solve((block,), y, m, d, C, tol, max_epochs, acc)
@@ -220,8 +274,10 @@ def fit(rows, y, mask, n: int, cfg: dict, acc=jnp.float32) -> Fit:
 
 def _pad_rows(rows, n: int, pad: int):
     """Insert ``pad`` zero rows after the job's ``n`` rows."""
-    z = jnp.zeros((pad,) + rows.shape[1:], rows.dtype)
-    return jnp.concatenate([rows[:n], z, rows[n:]])
+    def one(a):
+        z = jnp.zeros((pad,) + a.shape[1:], a.dtype)
+        return jnp.concatenate([a[:n], z, a[n:]])
+    return _rows(rows, one)
 
 
 def final(rows, y, n: int, sv_ids, cfg: dict, acc=jnp.float32):

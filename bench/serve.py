@@ -10,7 +10,11 @@ and never holds a second wave's rows (a back-fill at saturation).
 Set-up fits every tenant's archive in one batched sweep
 (``fit_mapreduce_sweep``, the library entry the service folds with),
 registers the tenants and runs one warm-up wave of the window's shape,
-so that nothing compiles inside the window.
+so that nothing compiles inside the window. Rows are the program's row
+type for the configuration's ``row_format`` (``program_rows``): dense
+arrays, or blocked-CSR ``repro.sparse.SparseRows``; the sweep pads and
+stacks them with ``repro.sparse``'s row ops, which are ``jnp``'s for
+dense rows.
 """
 from __future__ import annotations
 
@@ -27,15 +31,28 @@ def next_pow2(n: int) -> int:
 
 
 def mr_config(cfg: dict):
+    from bench.gen import nnz_cap
     from repro.core import MRSVMConfig, SVMConfig
+    csr = cfg["row_format"] == "sparse_csr"
     svm = SVMConfig(C=float(cfg["C"]), max_epochs=int(cfg["max_epochs"]),
                     tol=float(cfg["tol"]),
                     sv_threshold=float(cfg["sv_threshold"]),
-                    row_format=cfg["row_format"])
+                    row_format=cfg["row_format"],
+                    nnz_cap=nnz_cap(cfg) if csr else 0)
     return MRSVMConfig(sv_capacity=int(cfg["sv_capacity"]), svm=svm,
                        gamma=float(cfg["gamma"]),
                        max_rounds=int(cfg["max_rounds"]),
                        shuffle_impl=cfg["shuffle_impl"])
+
+
+def program_rows(rows_model, tenant: int, batch: int, rows: int):
+    """``rows_model.make`` with its rows as the program's row type: a
+    CSR pair ``(cols, vals)`` becomes ``repro.sparse.SparseRows``."""
+    X, y = rows_model.make(tenant, batch, rows)
+    if rows_model.csr:
+        from repro.sparse import SparseRows
+        X = SparseRows(*X, rows_model.d)
+    return X, y
 
 
 def _annotate(name: str, on: bool):
@@ -92,6 +109,7 @@ class ServiceRun:
         from repro.core.mapreduce_svm import MapReduceSVM
         from repro.core.sweep import fit_mapreduce_sweep, stack_params
         from repro.serving import StreamingSVMService
+        from repro.sparse import rows_stack, rows_zeros_like
 
         cfg, tr = self.cfg, self.tr
         self.mr = mr = mr_config(cfg)
@@ -104,16 +122,17 @@ class ServiceRun:
         # empty jobs to the service's bucket width; with archives of a
         # wave's rows the sweep is the very program the waves run.
         n0 = int(tr["archive_rows"])
-        parts = [self.rows.make(s, -1, n0) for s in range(self.tenants)]
+        parts = [program_rows(self.rows, s, -1, n0)
+                 for s in range(self.tenants)]
         X = [p[0] for p in parts]
         y = [p[1] for p in parts]
         del parts
         width = next_pow2(self.tenants)
-        X += [jnp.zeros_like(X[0])] * (width - self.tenants)
+        X += [rows_zeros_like(X[0])] * (width - self.tenants)
         y += [jnp.zeros_like(y[0])] * (width - self.tenants)
         mask = jnp.stack([jnp.full((n0,), float(i < self.tenants), y[0].dtype)
                           for i in range(width)])
-        X, y = jnp.stack(X), jnp.stack(y)
+        X, y = rows_stack(X), jnp.stack(y)
         params = stack_params([mr.svm.params()] * width)
         res = fit_mapreduce_sweep(X, y, self.L, mr, params, mask=mask)
         del X, y, mask
@@ -145,7 +164,7 @@ class ServiceRun:
         for s, _ in items:
             k = self.next_batch[s]
             self.next_batch[s] += 1
-            X, y = self.rows.make(s, k, int(self.tr["batch_rows"]))
+            X, y = program_rows(self.rows, s, k, int(self.tr["batch_rows"]))
             batch.append((s, k, X, y))
         uids = self.svc.submit_many(
             [(self.names[s], X, y) for s, k, X, y in batch])

@@ -42,6 +42,49 @@ def test_control_reads_planted_faults(tiny_bench):
     assert line["who"] == "program" and line["correct"], line["checks"]
 
 
+@pytest.mark.parametrize("cell", ["c.fold", "c.train"])
+def test_lower_precision_fails_on_csr_cells(tiny_bench, cell):
+    """The control on CSR rows: the same reference with its values, its
+    ``Q_ii`` and its state in bfloat16, outside the cell's limits."""
+    import json
+
+    import jax
+
+    from bench import control, spec
+    bench = json.loads((tiny_bench / "BENCHMARK.json").read_text())
+    lines = control.readings(bench, spec.cell(bench, cell), 21, 1.0,
+                             tiny_bench.parent, jax.devices()[:1],
+                             who="control", bench_dir=tiny_bench)
+    by = {line["who"]: line for line in lines}
+    assert not by["control"]["correct"], by["control"]["checks"]
+    assert by["control"]["numbers"]["unchecked"] == 0
+
+
+@pytest.mark.parametrize("cell,fault", [("c.train", "half_fit"),
+                                        ("c.fold", "half_fold")])
+def test_control_reads_planted_faults_on_csr(tiny_bench, monkeypatch, cell,
+                                            fault):
+    """As ``test_control_reads_planted_faults``, on CSR cells, with the
+    program given float32 values (``tiny.widen_csr_values``)."""
+    import json
+
+    import jax
+
+    from bench import control, faults, spec
+    from bench.tests import tiny
+    tiny.widen_csr_values(monkeypatch)
+    bench = json.loads((tiny_bench / "BENCHMARK.json").read_text())
+    line, = control.readings(bench, spec.cell(bench, cell), 22, 1.0,
+                             tiny_bench.parent, jax.devices()[:1],
+                             fault=getattr(faults, fault),
+                             bench_dir=tiny_bench)
+    assert line["who"] == fault and not line["correct"]
+    line, = control.readings(bench, spec.cell(bench, cell), 22, 1.0,
+                             tiny_bench.parent, jax.devices()[:1],
+                             bench_dir=tiny_bench)
+    assert line["who"] == "program" and line["correct"], line["checks"]
+
+
 def test_merge_gap_is_the_closest_call():
     """``sv_gap`` is the least α kept less the most left out, over the
     partitions whose first row left out would also have been an SV."""

@@ -17,13 +17,17 @@ BASE = {"num_features": 512, "sv_capacity": 64, "C": 1.0, "max_epochs": 5,
 CONFIGS = {
     "tiny-dense": dict(BASE, row_format="dense", tenants=2, row_nnz=[8, 8],
                        signal_dims=16),
+    "tiny-csr": dict(BASE, row_format="sparse_csr", tenants=2,
+                     row_nnz=[8, 8], signal_dims=16),
 }
 TRAFFIC = {
     "fold": {"mode": "closed", "batch_rows": 64, "batches_per_wave": 1,
              "archive_rows": 128, "compare_folds": 2, "trace_s": 0.5},
     "train": {"mode": "train", "row_sets": 2, "compare_fits": 1},
 }
-# Readings at these sizes on the CPU: the program 1e-7; the bfloat16
+# Readings at these sizes on the CPU: the program 1e-7 on dense rows (on
+# CSR rows up to 4e-4, and `sv` over its limit on some seeds: the
+# program sums a CSR row's squared values in bfloat16); the bfloat16
 # control 2.5e-3 to 5e-2.
 LIMITS = {"sv": 0.02, "risk": 1e-3, "rounds": 0, "w": 1e-3, "final": 1e-3,
           "unchecked": 0}
@@ -32,8 +36,13 @@ CELLS = [
      "chips": 1, "why": "toy"},
     {"name": "d.train", "config": "tiny-dense", "traffic": "train",
      "chips": 1, "why": "toy"},
+    {"name": "c.fold", "config": "tiny-csr", "traffic": "fold",
+     "chips": 1, "why": "toy"},
+    {"name": "c.train", "config": "tiny-csr", "traffic": "train",
+     "chips": 1, "why": "toy"},
 ]
-RENAME = {"dense2t.fold-sat": "d.fold", "dense.train": "d.train"}
+RENAME = {"dense2t.fold-sat": ["d.fold", "c.fold"],
+          "dense.train": ["d.train", "c.train"]}
 
 
 def make(root: Path) -> Path:
@@ -52,7 +61,7 @@ def make(root: Path) -> Path:
     bench["workloads"] = CELLS
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = [RENAME[w] for w in m["workloads"]]
+            m["workloads"] = [t for w in m["workloads"] for t in RENAME[w]]
     (bd / "BENCHMARK.json").write_text(json.dumps(bench))
     return bd
 
@@ -65,3 +74,19 @@ def run(bd: Path, cell: str, seed: int, seconds: float = 2.0,
     return run_lib.run_cell(bench, spec.cell(bench, cell), seed, seconds,
                             trace, bd.parent, time.time(),
                             jax.devices()[:1], bench_dir=bd)
+
+
+def widen_csr_values(monkeypatch):
+    """Hand the program its CSR rows' values in float32, the same
+    numbers: a witness in which the program's bfloat16 sum of a CSR
+    row's squared values (``repro.sparse.row_sq_norms``) no longer
+    departs from the reference's float32 one."""
+    import jax.numpy as jnp
+    from bench import serve, train
+    make = serve.program_rows
+
+    def widened(rows_model, tenant, batch, rows):
+        X, y = make(rows_model, tenant, batch, rows)
+        return (X.astype(jnp.float32) if rows_model.csr else X), y
+    monkeypatch.setattr(serve, "program_rows", widened)
+    monkeypatch.setattr(train, "program_rows", widened)

@@ -8,7 +8,7 @@ import contextlib
 import time
 from typing import List
 
-from bench.serve import mr_config
+from bench.serve import mr_config, program_rows
 
 
 class TrainRun:
@@ -23,7 +23,7 @@ class TrainRun:
         import jax
         self.mr = mr_config(self.cfg)
         self.L = int(self.cfg["partitions"])
-        self.sets = [self.rows.make(0, j, self.n)
+        self.sets = [program_rows(self.rows, 0, j, self.n)
                      for j in range(int(self.tr["row_sets"]))]
         jax.block_until_ready(self.sets)
         self._fit(0, record=False)
